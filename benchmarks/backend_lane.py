@@ -17,9 +17,9 @@ Lanes (fixed specs; ``--smoke``/``--quick`` shrink sizes, not shapes):
 The lane shape is a *wide rate grid with few seeds* — the accelerator
 sweet spot — so multi-device hosts shard the rate axis
 (``run_sweep(..., shard_axis="rates")``) where the CI smoke sweeps shard
-seeds.  On CPU the fused lane's win is the measured sort collapse
-(``benchmarks/profile_engine.py``); on an accelerator the recorded
-``fused_speedup_wall`` row is the >=10x on-chip target's paper trail.
+seeds.  ``fused_speedup_wall`` records the fused/unfused wall ratio on
+whatever backend ran.  On TPU the lanes run in float32, the chip path's
+precision (see ``chip_smoke.py``); elsewhere in float64.
 
 ``python -m benchmarks.backend_lane [--smoke|--quick] [--no-append]
 [--out BENCH_sweeps.json] [--json]``
@@ -106,14 +106,9 @@ def lane_records(lanes) -> list[dict]:
             }
             for label, res in lanes
         },
-        # The on-chip acceptance metric: fused/unfused wall ratio for the
-        # identical quantized spec.  ~1.2-1.5x on CPU (sort collapse);
-        # the accelerator target is >=10x (no host sorts at all).
+        # Fused/unfused wall ratio for the identical quantized spec.
         "fused_speedup_wall": (
             q.wall_s / max(qf.wall_s, 1e-9) if q and qf else None
-        ),
-        "fused_speedup_target": (
-            10.0 if q and q.backend in ("gpu", "tpu") else None
         ),
     }
     records.append(summary)
@@ -150,11 +145,9 @@ def main(smoke: bool = False, quick: bool = False):
             f"{row['wall_s']:8.2f} {row['compile_s']:10.2f} "
             f"{row['jobs_per_s']:10.0f}"
         )
-    fs = summary["fused_speedup_wall"]
-    tgt = summary["fused_speedup_target"]
     lines.append(
-        f"fused/unfused quantized wall ratio: {fs:.2f}x"
-        + (f" (accelerator target >= {tgt:.0f}x)" if tgt else " (CPU lane)")
+        f"fused/unfused quantized wall ratio: "
+        f"{summary['fused_speedup_wall']:.2f}x ({summary['backend']})"
     )
     # Exactness across the lane: fused and unfused quantized sweeps must
     # agree bit-for-bit (same spec, same seeds, same chips).
@@ -174,7 +167,8 @@ if __name__ == "__main__":
 
     import jax
 
-    jax.config.update("jax_enable_x64", True)
+    # The Pallas allocate takes float32 only, so the chip runs float32.
+    jax.config.update("jax_enable_x64", jax.default_backend() != "tpu")
     text, records = main(smoke="--smoke" in sys.argv,
                          quick="--quick" in sys.argv)
     if "--json" in sys.argv:

@@ -170,11 +170,17 @@ def run(m: int = 4096, engine_m: int = 1024, p: float = 0.5,
         chips = _seed_quantize(theta, n_chips, min_chips=min_chips)
         return chips, speedup(chips.astype(x.dtype), pv)
 
+    # The compiled kernel on TPU, which takes float32 sizes only; the
+    # interpreter elsewhere (its wall time there is not the kernel's).
+    on_tpu = jax.default_backend() == "tpu"
+    x_pallas = x.astype(jnp.float32) if on_tpu else x
+
     def alloc_pallas(x_act, pv):
         _theta, chips = hesrpt_alloc_fused(
-            x_act, pv, n_chips, min_chips=min_chips, impl="interpret"
+            x_act, pv, n_chips, min_chips=min_chips,
+            impl="pallas" if on_tpu else "interpret",
         )
-        return chips, speedup(chips.astype(x.dtype), pv)
+        return chips, speedup(chips.astype(x_act.dtype), pv)
 
     components = [
         ("policy_theta", lambda xv, pv: hesrpt(xv, pv), (x, pj)),
@@ -188,7 +194,8 @@ def run(m: int = 4096, engine_m: int = 1024, p: float = 0.5,
         ("alloc_seed", alloc_seed, (x, pj)),
         ("alloc_unfused", rule, (x, pj)),
         ("alloc_fused_ref", fused_rule, (x, pj)),
-        ("alloc_pallas_interp", alloc_pallas, (x, pj)),
+        ("alloc_pallas" if on_tpu else "alloc_pallas_interp", alloc_pallas,
+         (x_pallas, pj.astype(x_pallas.dtype))),
     ]
     # Ratios use the min over repeats: on a shared machine the mean is
     # contaminated by scheduler interference, while the min approaches the

@@ -16,30 +16,18 @@ to see per-policy device time next to XLA's own slices.
 
 from __future__ import annotations
 
-import os
 import sys
 import time
-from pathlib import Path
 
 import jax
+
+from repro.compile_cache import enable_compile_cache
 
 # Scheduler math (closed forms vs simulation) wants f64; model/kernel code
 # pins its own dtypes explicitly so this only affects the core benchmarks.
 jax.config.update("jax_enable_x64", True)
-
-# Persistent compilation cache: the benchmark sections recompile the same
-# engine scans every run — cache the executables on disk so repeat runs
-# (and CI, which restores the directory via actions/cache) skip straight
-# to execution.  JAX_COMPILATION_CACHE_DIR overrides the repo-local
-# default; threshold 0 caches even sub-second smoke-size programs.
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        str(Path(__file__).resolve().parent.parent / ".jax_cache"),
-    ),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+# The benchmark sections recompile the same engine scans every run.
+enable_compile_cache()
 
 
 def _section(title):

@@ -22,10 +22,10 @@ is the single replacement path:
      a ``max_jobs_in_flight`` memory budget; a 2,000-jobs x 200-seeds x
      5-loads grid (2M simulated jobs per policy) runs on CPU without OOM.
      Chunked results are bit-for-bit the unchunked ``vmap`` (tested).
-  2. **Device sharding** — opt-in ``shard_map`` over the seed axis (the
-     version-tolerant shims in ``models/common.py``), so multi-device
-     hosts split seeds across devices; sharded == single-device (tested
-     under ``XLA_FLAGS=--xla_force_host_platform_device_count``).
+  2. **Device sharding** — opt-in ``jax.shard_map`` over the seed or the
+     rate axis, so multi-device hosts split the grid across devices;
+     sharded == single-device bit for bit (tested under
+     ``XLA_FLAGS=--xla_force_host_platform_device_count``).
   3. **Structured artifacts** — every run returns a :class:`SweepResult`
      (spec, per-seed stats, wall/compile time, backend, chunking) that
      serializes to JSON; every ``run_sweep`` call also appends a compact
@@ -673,7 +673,21 @@ def _build_fn(
     import jax.numpy as jnp
 
     one = _cell_fn(spec, name)
-    inner = jax.vmap(jax.vmap(one, in_axes=(0, None)), in_axes=(None, 0))
+
+    def inner(keys, rates):
+        # One vmap over the flat (rate, seed) lanes, rate-major, not a
+        # vmap over rates of a vmap over seeds.  Every per-cell reduction
+        # is then a [lanes, jobs] -> [lanes] reduce whatever slice of the
+        # grid a device holds; the TPU compiler lowered the nested
+        # [rates, seeds, jobs] form differently for a one-rate shard, and
+        # its means came out a few ulp off the whole grid's.  The price is
+        # compile time on the TPU, which grows with the count of lanes that
+        # carry their own key: R x S lanes compile like R*S seeds.
+        R, S = rates.shape[0], keys.shape[0]
+        lane_keys = jnp.broadcast_to(keys, (R, *keys.shape))
+        lane_keys = lane_keys.reshape(R * S, *keys.shape[1:])
+        out = jax.vmap(one)(lane_keys, jnp.repeat(rates, S))
+        return tuple(a.reshape(R, S, *a.shape[1:]) for a in out)
 
     def over_seeds(keys, rates):
         # Rate-axis shards see a slice of the rate grid, so the rate count
@@ -701,8 +715,6 @@ def _build_fn(
 
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.models.common import shard_map
-
     devices = np.asarray(jax.devices())
     mesh = Mesh(devices, (shard_axis,))
     if shard_axis == "rates":
@@ -721,11 +733,17 @@ def _build_fn(
         )
 
     def sharded(keys, rates):
-        return shard_map(
+        # Each device runs its slice of the grid alone: no collective runs
+        # inside the scan, so there is no cross-device value for the
+        # varying-manual-axes check to track.  With the check on, the
+        # scan's carry (device-invariant initial state) and its output
+        # (varying) get different types and tracing fails.
+        return jax.shard_map(
             over_seeds,
             mesh=mesh,
             in_specs=in_specs,
             out_specs=out_specs,
+            check_vma=False,
         )(keys, rates)
 
     return sharded

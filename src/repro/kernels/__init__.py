@@ -5,21 +5,12 @@ serve the *scheduled substrate*: flash attention (causal/SWA/GQA), the Mamba2
 SSD chunked scan, and the RG-LRU linear recurrence.
 """
 
-import jax.experimental.pallas.tpu as pltpu
-
-# jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``; accept
-# either so the kernels build on every jax the toolchain ships.  This alias
-# must be defined before the submodule imports below — the kernel modules
-# import it from this (then partially-initialized) package.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-from repro.kernels import alloc, ops, ref  # noqa: E402
-from repro.kernels.flash_attention import flash_attention  # noqa: E402
-from repro.kernels.rglru_scan import rglru_scan  # noqa: E402
-from repro.kernels.ssd_scan import ssd_scan  # noqa: E402
+from repro.kernels import alloc, ops, ref
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rglru_scan import rglru_scan
+from repro.kernels.ssd_scan import ssd_scan
 
 __all__ = [
-    "CompilerParams",
     "alloc",
     "flash_attention",
     "ops",

@@ -168,9 +168,21 @@ def _alloc_kernel(
     min_chips: int,
     block_c: int,
 ):
+    # Every value in the kernel is 32-bit: the TPU kernel compiler has no
+    # 64-bit types, and under jax_enable_x64 an integer reduction or a bare
+    # Python scalar handed to jnp.where would come out 64-bit.  So integer
+    # sums name their dtype, where-fills are typed constants, and the
+    # bisection is a static loop (no loop counter).  Sums reduce the lane
+    # axis only: Mosaic lowers a reduction to a rank-0 result through a
+    # proxy jnp.sum that promotes int32 to int64 under x64.
+    i32 = jnp.int32
     Mp = x_ref.shape[1]
     n_blocks = Mp // block_c
-    col = jax.lax.broadcasted_iota(jnp.int32, (1, Mp), 1)
+    col = jax.lax.broadcasted_iota(i32, (1, Mp), 1)
+    izero = jnp.zeros((), i32)
+
+    def isum(a):
+        return jnp.sum(a.astype(i32), axis=1, dtype=i32, keepdims=True)
 
     def positions(key):
         """Stable-argsort position of every column of ``key`` ([1, Mp]).
@@ -178,23 +190,23 @@ def _alloc_kernel(
         O(M^2) comparison counting, chunked so the pairwise tile is
         [block_c, Mp]; the static Python loop unrolls (no sort primitive).
         """
-        pos = jnp.zeros((1, Mp), jnp.int32)
+        pos = jnp.zeros((1, Mp), i32)
         for b in range(n_blocks):
             kj = jnp.swapaxes(key[:, b * block_c : (b + 1) * block_c], 0, 1)
-            jrow = (
-                jax.lax.broadcasted_iota(jnp.int32, (block_c, 1), 0)
-                + b * block_c
-            )
+            jrow = jax.lax.broadcasted_iota(i32, (block_c, 1), 0) + b * block_c
             before = (kj < key) | ((kj == key) & (jrow < col))
-            pos = pos + jnp.sum(before.astype(jnp.int32), axis=0, keepdims=True)
+            pos = pos + jnp.sum(
+                before.astype(i32), axis=0, dtype=i32, keepdims=True
+            )
         return pos
 
     x = x_ref[...]
     dtype = x.dtype
+    zero = jnp.zeros((), dtype)
     inf = jnp.asarray(jnp.inf, dtype)
     active = (x > 0) & (col < M)
-    ranks = jnp.where(active, positions(jnp.where(active, -x, inf)) + 1, 0)
-    m = jnp.sum(active.astype(jnp.int32), keepdims=True)
+    ranks = jnp.where(active, positions(jnp.where(active, -x, inf)) + 1, izero)
+    m = isum(active)
 
     # Thm-7 brackets — the exact op sequence of hesrpt_theta_from_ranks.
     p = p_ref[...]
@@ -203,60 +215,54 @@ def _alloc_kernel(
     m_safe = jnp.maximum(m, 1).astype(dtype)
     hi = (rf / m_safe) ** c
     lo = ((rf - 1.0) / m_safe) ** c
-    theta = jnp.where(active, hi - lo, 0.0)
+    theta = jnp.where(active, hi - lo, zero)
     theta_ref[...] = theta
 
     if n_chips <= 0 or min_chips <= 0:
-        chips_ref[...] = jnp.zeros((1, Mp), jnp.int32)
+        chips_ref[...] = jnp.zeros((1, Mp), i32)
         return
 
     # Largest-remainder quantization: _quantize_from_ranks, positions()
     # replacing its one argsort.
     cap = n_chips // min_chips
     active0 = theta > 0
-    n_active = jnp.sum(active0.astype(jnp.int32), keepdims=True)
+    n_active = isum(active0)
     servable = active0 & (ranks > m - cap)
     over = n_active * min_chips > n_chips
-    sub = jnp.where(servable, theta, 0.0)
-    tot = jnp.sum(sub, keepdims=True)
-    theta_eff = jnp.where(over, jnp.where(tot > 0, sub / tot, 0.0), theta)
+    sub = jnp.where(servable, theta, zero)
+    tot = jnp.sum(sub, axis=1, keepdims=True)
+    theta_eff = jnp.where(over, jnp.where(tot > 0, sub / tot, zero), theta)
     active_q = theta_eff > 0
 
     raw = theta_eff * n_chips
     fl = jnp.floor(raw)
     frac = raw - fl
-    base = jnp.where(active_q, jnp.maximum(fl, float(min_chips)), 0.0)
-    base = base.astype(jnp.int32)
+    base = jnp.where(active_q, jnp.maximum(fl, float(min_chips)), zero)
+    base = base.astype(i32)
 
-    K = jnp.maximum(jnp.sum(base, keepdims=True) - n_chips, 0)
-    capj = jnp.maximum(base - min_chips, 0) * (base > min_chips).astype(jnp.int32)
+    K = jnp.maximum(isum(base) - n_chips, 0)
+    capj = jnp.maximum(base - min_chips, 0) * (base > min_chips).astype(i32)
 
-    def bisect(_, lohi):
-        lo_, hi_ = lohi
-        mid = (lo_ + hi_) // 2
-        ge = jnp.sum(jnp.minimum(capj, mid), keepdims=True) >= K
-        return jnp.where(ge, lo_, mid + 1), jnp.where(ge, mid, hi_)
-
-    n_bits = (n_chips + 1).bit_length()
-    r_star, _hi2 = jax.lax.fori_loop(
-        0,
-        n_bits,
-        bisect,
-        (jnp.zeros((1, 1), jnp.int32), jnp.full((1, 1), n_chips, jnp.int32)),
-    )
+    lo_ = jnp.zeros((1, 1), i32)
+    hi_ = jnp.full((1, 1), n_chips, i32)
+    for _ in range((n_chips + 1).bit_length()):
+        mid = jax.lax.shift_right_logical(lo_ + hi_, i32(1))
+        ge = isum(jnp.minimum(capj, mid)) >= K
+        lo_, hi_ = jnp.where(ge, lo_, mid + 1), jnp.where(ge, mid, hi_)
+    r_star = lo_
     full = jnp.minimum(capj, jnp.maximum(r_star - 1, 0))
-    extra_needed = K - jnp.sum(full, keepdims=True)
+    extra_needed = K - isum(full)
     elig = capj >= jnp.maximum(r_star, 1)
     trim = K > 0
     key_q = jnp.where(
         trim, jnp.where(elig, frac, inf), jnp.where(active_q, -frac, inf)
     )
     pos = positions(key_q)
-    extra = (elig & (pos < extra_needed)).astype(jnp.int32)
+    extra = (elig & (pos < extra_needed)).astype(i32)
     base = base - full - extra
 
-    remainder = n_chips - jnp.sum(base, keepdims=True)
-    chips_ref[...] = base + (active_q & (pos < remainder)).astype(jnp.int32)
+    remainder = n_chips - isum(base)
+    chips_ref[...] = base + (active_q & (pos < remainder)).astype(i32)
 
 
 @functools.partial(
@@ -271,6 +277,12 @@ def _alloc_pallas(
     block_c: int = 128,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
+    if not interpret and x.dtype != jnp.float32:
+        raise TypeError(
+            f"the compiled Pallas allocate takes float32 sizes, got {x.dtype}: "
+            "the TPU kernel compiler has no 64-bit types.  Pass float32 "
+            "sizes, or impl='ref' for the jnp allocate in any precision."
+        )
     M = x.shape[0]
     pad = -M % block_c if M else block_c
     Mp = max(M + pad, block_c)

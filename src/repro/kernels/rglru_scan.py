@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import CompilerParams
-
 
 def _rglru_kernel(
     a_ref,  # [1, T, Wb] decay in (0, 1]
@@ -90,7 +88,7 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, block_t, block_w), lambda b, w, t: (b, t, w)),
         out_shape=jax.ShapeDtypeStruct((B, S_p, W_p), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -9,36 +9,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-tolerant ``shard_map`` (same rationale as the
-    ``repro.kernels.CompilerParams`` alias): newer jax exposes
-    ``jax.shard_map`` with the ``check_vma`` spelling, older jax only has
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` — and some
-    releases in between export the top-level name while still spelling the
-    kwarg ``check_rep``, so the accepted kwarg is detected from the
-    signature rather than inferred from where the function lives.
-    """
-    import inspect
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        has_vma = "check_vma" in inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # C-accelerated / exotic wrappers
-        has_vma = hasattr(jax, "shard_map")
-    kw = {"check_vma": check_vma} if has_vma else {"check_rep": check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-def use_mesh(mesh):
-    """Version-tolerant ambient-mesh context: newer jax spells it
-    ``jax.set_mesh(mesh)``; on older jax the ``Mesh`` object itself is the
-    context manager."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
 @dataclass(frozen=True)
 class ParallelConfig:
     """Mesh handle threaded into model code that needs explicit collectives

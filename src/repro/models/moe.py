@@ -24,7 +24,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import shard_map
 from repro.models.layers import split_tree, uniform_scale_init
 
 
@@ -142,7 +141,7 @@ def moe_apply_ragged(p, x, cfg, parallel):
     fn = functools.partial(
         _moe_local_ragged, cfg=cfg, model_axis=mp, aux_axes=tuple(dp) + (mp,)
     )
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         fn,
         mesh=parallel.mesh,
         in_specs=(

@@ -37,13 +37,21 @@ jobs through ``report_progress``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from repro.core.policies import make_policy
 from repro.sched.estimator import SpeedupEstimator, blended_p, pooled_p_hat
 from repro.sched.quantize import quantize_allocation, snap_to_slices
+
+
+@functools.partial(jax.jit, static_argnames="name")
+def _policy_theta(name: str, x, p, n_servers, alpha):
+    """One compiled policy call per (policy, padded length)."""
+    return make_policy(name, n_servers=n_servers, alpha=alpha)(x, p)
 
 
 @dataclass
@@ -215,15 +223,18 @@ class ClusterScheduler:
         if self.class_aware:
             theta = self._class_theta(act)
         else:
-            x = jnp.asarray([j.remaining for j in act])
-            pol = make_policy(
-                self.policy_name,
-                n_servers=float(self.n_chips),
-                alpha=float(
-                    np.median([j.remaining for j in act]) * p / self.n_chips
-                ),
-            )
-            theta = np.asarray(pol(x, p), dtype=np.float64)
+            # The active count changes at nearly every epoch.  Padding the
+            # sizes to a power of two with zeros, which every policy treats
+            # as inactive (as the engine's scan does), lets the compiled
+            # policy be reused instead of recompiled at each new count.
+            rem = [j.remaining for j in act]
+            xp = np.zeros(max(8, 1 << (len(act) - 1).bit_length()))
+            xp[: len(act)] = rem
+            theta = np.asarray(_policy_theta(
+                self.policy_name.lower(), jnp.asarray(xp), p,
+                float(self.n_chips),
+                float(np.median(rem) * p / self.n_chips),
+            ), dtype=np.float64)[: len(act)]
         if self.quantize:
             chips = quantize_allocation(theta, self.n_chips, min_chips=self.min_chips)
             if self.snap_slices:
@@ -309,17 +320,15 @@ class ClusterScheduler:
         is a pure ``core.multiclass`` rule; the plain single-class mode
         still needs uniform p (its blended-p physics are not a pure
         per-job rule — the estimator mode has no such constraint, its
-        physics are per-job true p).  It also needs float64 JAX (else the
-        trajectory would silently drop to f32 and near-tie chip decisions
-        could flip vs the f64 NumPy oracle path) — callers without
-        ``jax_enable_x64`` get the Python loop."""
-        import jax
+        physics are per-job true p).
 
+        The engine runs in the caller's precision: float64 under
+        ``jax_enable_x64``, float32 otherwise (the TPU's native width).  In
+        float32, whole-chip decisions at near-ties of the largest-remainder
+        rounding may differ from the float64 per-event loop."""
         from repro.core.multiclass import MULTICLASS_POLICY_NAMES
 
         act = self.active_jobs()
-        if not jax.config.jax_enable_x64:
-            return False
         if self.class_aware:
             return self.policy_name.lower() in MULTICLASS_POLICY_NAMES
         if self.use_estimator:
@@ -520,10 +529,11 @@ class ClusterScheduler:
         Delegates to the scan engine when eligible (one jit'd device call);
         ``use_engine=False`` forces the per-event Python epoch loop
         (allocate -> advance to next departure -> repeat), which is the
-        oracle the engine path is tested against event-for-event.
+        oracle the engine path is tested against event-for-event.  The
+        summary's ``"path"`` says which ran: ``"engine"`` or ``"events"``.
         """
         if use_engine and self.active_jobs() and self._engine_eligible():
-            return self._run_fluid_engine()
+            return {**self._run_fluid_engine(), "path": "engine"}
         guard = 0
         while self.active_jobs():
             self.allocations()
@@ -531,4 +541,4 @@ class ClusterScheduler:
             guard += 1
             if guard > 10 * len(self.jobs) + 100:
                 raise RuntimeError("scheduler failed to converge")
-        return self._summary()
+        return {**self._summary(), "path": "events"}

@@ -29,7 +29,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.data.pipeline import DataConfig, ShardedSyntheticStream
 from repro.models import ModelOptions, build_model
-from repro.models.common import shard_map
 from repro.sched.cluster import ClusterScheduler, Job
 from repro.sched.stragglers import StragglerDetector
 from repro.train import checkpoint
@@ -104,7 +103,7 @@ class ElasticJob:
             params, opt, _ = apply_updates(params, grads, opt, opt_cfg)
             return params, opt, err, jax.lax.pmean(loss, "data")
 
-        shmapped = shard_map(
+        shmapped = jax.shard_map(
             local_step,
             mesh=self.mesh,
             in_specs=(P(), P(), P(), P("data")),

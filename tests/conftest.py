@@ -3,71 +3,19 @@
 - float64 is enabled for the scheduler-math tests (closed-form vs simulator
   comparisons need it).  Model/kernel code specifies its dtypes explicitly,
   so this does not change model behaviour.
+- The persistent compilation cache is the one every entry point shares
+  (``repro.compile_cache``): the suite compiles hundreds of distinct XLA
+  programs, and repeat runs read them back from disk.
 - NOTE: we deliberately do NOT set XLA_FLAGS here; distribution tests that
   need many fake devices spawn subprocesses with their own flags so ordinary
   tests see the real single-CPU device.
-- Known seed-state failures (tests/KNOWN_FAILURES.md) are marked
-  xfail(strict=False) at collection, so any run — tier-1 or full — enforces
-  "no new failures" instead of tolerating a red suite.  Fix a test, delete
-  its line from KNOWN_FAILURES.md, and a regression breaks CI again.
+- Tests that fail today are listed with their cause in
+  ``tests/KNOWN_FAILURES.md``.  They are not marked: they fail visibly.
 """
 
-import os
-import re
-from pathlib import Path
-
 import jax
-import pytest
+
+from repro.compile_cache import enable_compile_cache
 
 jax.config.update("jax_enable_x64", True)
-
-# Persistent compilation cache (shared with benchmarks/run.py): the suite
-# compiles hundreds of distinct XLA programs; caching them on disk makes
-# repeat local runs and CI (which restores the directory via actions/cache)
-# skip recompilation.  JAX_COMPILATION_CACHE_DIR overrides the repo-local
-# default; threshold 0 caches even sub-second test-size programs.
-jax.config.update(
-    "jax_compilation_cache_dir",
-    os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        str(Path(__file__).resolve().parent.parent / ".jax_cache"),
-    ),
-)
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-
-_KNOWN_FAILURES = Path(__file__).parent / "KNOWN_FAILURES.md"
-
-
-def _known_failure_nodeids() -> frozenset[str]:
-    if not _KNOWN_FAILURES.exists():
-        return frozenset()
-    ids = re.findall(r"^- `([^`]+)`", _KNOWN_FAILURES.read_text(), re.M)
-    return frozenset(ids)
-
-
-def pytest_collection_modifyitems(config, items):
-    known = _known_failure_nodeids()
-    for item in items:
-        if item.nodeid in known:
-            item.add_marker(pytest.mark.xfail(
-                reason="known seed failure — tracked in tests/KNOWN_FAILURES.md",
-                strict=False,
-            ))
-
-
-@pytest.fixture(scope="module")
-def fresh_compile_cache():
-    """Drop jax's executable cache before a compile-heavy module runs.
-
-    Late in the suite, after a few hundred distinct XLA programs have been
-    compiled in-process, jaxlib 0.4.x's CPU backend segfaults inside
-    backend_compile on the next large scan (reproducibly, and only then —
-    the same compile is fine standalone or after either half of the suite,
-    with >100 GB free).  Dropping the executable cache releases the
-    accumulated JIT state and keeps the compile below whatever threshold
-    it trips.  Opt in per module with
-    ``pytestmark = pytest.mark.usefixtures("fresh_compile_cache")`` (or an
-    autouse wrapper) from any module that compiles large scans and can run
-    late in the alphabetical order.
-    """
-    jax.clear_caches()
+enable_compile_cache()

@@ -112,7 +112,7 @@ def test_moe_ragged_shard_map_matches_dense():
         """
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import smoke_config
-        from repro.models.common import ParallelConfig, use_mesh
+        from repro.models.common import ParallelConfig
         from repro.models.moe import moe_apply, moe_init
 
         mesh = jax.make_mesh((4, 2), ("data", "model"))
@@ -121,7 +121,7 @@ def test_moe_ragged_shard_map_matches_dense():
         p = moe_init(jax.random.PRNGKey(0), cfg, jnp.float32)
         x = jnp.asarray(np.random.default_rng(1).standard_normal((8, 16, cfg.d_model)),
                         jnp.float32)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_r, aux_r = jax.jit(lambda p, x: moe_apply(p, x, cfg, impl="ragged",
                                                         parallel=par))(p, x)
         y_d, aux_d = moe_apply(p, x, cfg, impl="dense")

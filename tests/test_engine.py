@@ -128,6 +128,7 @@ def test_quantized_batch_matches_cluster_event_for_event(name):
             b.add_job(Job(f"j{i}", size=float(s), p=0.5))
         ra = a.run_fluid_to_completion(use_engine=True)
         rb = b.run_fluid_to_completion(use_engine=False)
+        assert (ra["path"], rb["path"]) == ("engine", "events")
         ea = [e["chips"] for e in a.events if e["event"] == "allocate"]
         eb = [e["chips"] for e in b.events if e["event"] == "allocate"]
         assert ea == eb
@@ -139,6 +140,63 @@ def test_quantized_batch_matches_cluster_event_for_event(name):
         np.testing.assert_allclose(ra["total_flow_time"],
                                    rb["total_flow_time"], rtol=1e-9)
         np.testing.assert_allclose(ra["makespan"], rb["makespan"], rtol=1e-9)
+
+
+def test_cluster_engine_runs_in_float32_without_x64():
+    """Without x64 the scheduler still runs the engine (float32), and says
+    so.  Against the float64 per-event loop fed the same float32 sizes,
+    chips differ only where float32 breaks a largest-remainder tie the
+    other way (one chip per job, on a few percent of events) and flows
+    agree to float32 accuracy.
+
+    How many events tie depends on the cluster: at p = 0.5 the brackets
+    are ``(2r - 1) / m**2``, so for some active counts ``m`` the rounded
+    shares of many jobs tie exactly (40 jobs on 64 chips tie at most
+    events).  This batch, 200 jobs on 256 chips, is of the kind
+    ``chip_smoke.py`` checks."""
+    from benchmarks.quantized import compare_events
+
+    sizes = (np.random.default_rng(3).pareto(1.5, 200) + 1.0).astype(
+        np.float32)
+
+    def scheduler():
+        s = ClusterScheduler(256, policy="hesrpt")
+        for i, x in enumerate(sizes):
+            s.add_job(Job(f"j{i}", size=float(x), p=0.5))
+        return s
+
+    a, b = scheduler(), scheduler()
+    with jax.enable_x64(False):
+        ra = a.run_fluid_to_completion()
+    rb = b.run_fluid_to_completion(use_engine=False)
+    assert (ra["path"], rb["path"]) == ("engine", "events")
+
+    def allocs(s):
+        return [(e["t"], e["chips"]) for e in s.events
+                if e["event"] == "allocate"]
+
+    differing, max_diff, t_rel = compare_events(allocs(a), allocs(b))
+    assert max_diff <= 1 and differing <= 0.05 * len(allocs(b)), (
+        differing, max_diff)
+    assert t_rel < 1e-2
+    np.testing.assert_allclose(ra["mean_flow_time"], rb["mean_flow_time"],
+                               rtol=1e-5)
+
+
+def test_compare_events_pairs_by_live_jobs():
+    """Events pair in order by their set of live jobs; unpaired events on
+    either side count as differing."""
+    from benchmarks.quantized import compare_events
+
+    ref = [(0.0, {"a": 2, "b": 2}), (1.0, {"a": 4}), (3.0, {"c": 4})]
+    assert compare_events(ref, ref) == (0, 0, 0.0)
+    off = [(0.0, {"a": 3, "b": 1}), (1.1, {"a": 4}), (3.0, {"c": 4})]
+    differing, max_diff, t_rel = compare_events(off, ref)
+    assert (differing, max_diff) == (1, 1)
+    assert t_rel == pytest.approx(0.1)
+    # One side splits a departure into two events: one extra, unpaired.
+    split = ref[:2] + [(2.0, {"a": 1, "c": 3})] + ref[2:]
+    assert compare_events(split, ref)[:2] == (1, 0)
 
 
 def test_quantized_online_matches_cluster_event_for_event():

@@ -27,8 +27,6 @@ from repro.core import engine, make_policy, make_rank_policy, make_scenario
 from repro.core.scenarios import stream_tape
 from repro.core.telemetry import make_probe, scalar_values
 
-pytestmark = pytest.mark.usefixtures("fresh_compile_cache")
-
 N_JOBS = 40
 
 

@@ -39,7 +39,6 @@ from repro.core.superstep import (
 )
 from repro.core.sweeps import Sweep, run_sweep
 
-pytestmark = pytest.mark.usefixtures("fresh_compile_cache")
 
 SCENARIO_NAMES = (
     "batch", "poisson", "deterministic", "bursty",

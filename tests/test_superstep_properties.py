@@ -28,7 +28,6 @@ hypothesis = pytest.importorskip(
 from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
-pytestmark = pytest.mark.usefixtures("fresh_compile_cache")
 
 POLICIES = ("hesrpt", "equi", "srpt")
 

@@ -29,14 +29,6 @@ N_JOBS = 24
 SAMPLE = Path(__file__).parent.parent / "examples" / "sample_schedule_trace.json"
 
 
-@pytest.fixture(scope="module", autouse=True)
-def _fresh_compile_cache(fresh_compile_cache):
-    # This file runs near the end of the suite and compiles large recorded
-    # scans — see the shared ``fresh_compile_cache`` fixture in conftest.py
-    # for the jaxlib 0.4.x CPU-backend rationale; autouse it here.
-    pass
-
-
 def _recorded(kind, seed=0, rate=2.0, n_jobs=N_JOBS, p=0.5):
     scn = make_scenario("poisson", p=p)(jax.random.key(seed), n_jobs, rate)
     dtype = scn.x0.dtype
