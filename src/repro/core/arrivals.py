@@ -61,6 +61,7 @@ class OnlineSimResult(NamedTuple):
     makespan: jax.Array  # scalar, last departure time
 
 
+@jax.named_scope("engine.reduce")
 def _finalize(x0, arrival_times, times, p, n_servers) -> OnlineSimResult:
     """Per-job flow times / slowdowns from completion times (input order)."""
     flows = times - arrival_times

@@ -532,65 +532,68 @@ def run(
     srule = as_stateful(rule)
 
     def body(carry, _):
-        if telemetry is None:
-            x, t, i, times, st = carry
-        else:
-            x, t, i, times, st, tel = carry
-        active = (idx < i) & (x > 0)
-        x_act = jnp.where(active, x, 0.0)
-        if p_drift is None:
-            p_now = p
-            dt_drift = jnp.inf
-            t_next_drift = jnp.inf
-        else:
-            r = jnp.searchsorted(drift_t, t, side="right")
-            p_now = drift_v[r]
-            n_d = drift_t.shape[0]
-            t_next_drift = jnp.where(
-                r < n_d, drift_t[jnp.minimum(r, n_d - 1)], jnp.inf
+        with jax.named_scope("engine.advance"):
+            if telemetry is None:
+                x, t, i, times, st = carry
+            else:
+                x, t, i, times, st, tel = carry
+            active = (idx < i) & (x > 0)
+            x_act = jnp.where(active, x, 0.0)
+            if p_drift is None:
+                p_now = p
+                dt_drift = jnp.inf
+                t_next_drift = jnp.inf
+            else:
+                r = jnp.searchsorted(drift_t, t, side="right")
+                p_now = drift_v[r]
+                n_d = drift_t.shape[0]
+                t_next_drift = jnp.where(
+                    r < n_d, drift_t[jnp.minimum(r, n_d - 1)], jnp.inf
+                )
+                dt_drift = jnp.maximum(t_next_drift - t, 0.0)
+        with jax.named_scope("engine.allocate"):
+            alloc, rate = srule.allocate(st, x_act, p_now)
+        with jax.named_scope("engine.advance"):
+            tt = jnp.where(active & (rate > 0), x / rate, jnp.inf)
+            dt_dep = jnp.min(tt)  # inf when nothing is active
+            t_next_arr = jnp.where(i < M, arr[jnp.minimum(i, M - 1)], jnp.inf)
+            dt_arr = jnp.maximum(t_next_arr - t, 0.0)
+            dt = jnp.minimum(jnp.minimum(dt_dep, dt_arr), dt_drift)
+            any_event = jnp.isfinite(dt)
+            dt = jnp.where(any_event, dt, 0.0)
+            # Landing on an arrival pins t to the exact arrival time so the
+            # searchsorted admission below cannot miss it to float rounding
+            # (same for a drift boundary: the next epoch's regime lookup uses
+            # side="right", so t == boundary already reads the new exponent).
+            admit = any_event & (dt_arr <= jnp.minimum(dt_dep, dt_drift))
+            take_dep = any_event & (dt_dep <= jnp.minimum(dt_arr, dt_drift))
+            take_drift = any_event & ~admit & ~take_dep
+            t_new = jnp.where(
+                admit, t_next_arr, jnp.where(take_drift, t_next_drift, t + dt)
             )
-            dt_drift = jnp.maximum(t_next_drift - t, 0.0)
-        alloc, rate = srule.allocate(st, x_act, p_now)
-        tt = jnp.where(active & (rate > 0), x / rate, jnp.inf)
-        dt_dep = jnp.min(tt)  # inf when nothing is active
-        t_next_arr = jnp.where(i < M, arr[jnp.minimum(i, M - 1)], jnp.inf)
-        dt_arr = jnp.maximum(t_next_arr - t, 0.0)
-        dt = jnp.minimum(jnp.minimum(dt_dep, dt_arr), dt_drift)
-        any_event = jnp.isfinite(dt)
-        dt = jnp.where(any_event, dt, 0.0)
-        # Landing on an arrival pins t to the exact arrival time so the
-        # searchsorted admission below cannot miss it to float rounding
-        # (same for a drift boundary: the next epoch's regime lookup uses
-        # side="right", so t == boundary already reads the new exponent).
-        admit = any_event & (dt_arr <= jnp.minimum(dt_dep, dt_drift))
-        take_dep = any_event & (dt_dep <= jnp.minimum(dt_arr, dt_drift))
-        take_drift = any_event & ~admit & ~take_dep
-        t_new = jnp.where(
-            admit, t_next_arr, jnp.where(take_drift, t_next_drift, t + dt)
-        )
-        x_new = jnp.where(active, x - dt * rate, x)
-        # The argmin job departs BY CONSTRUCTION when the departure is the
-        # next event; float residue (~eps*x) must not be allowed to keep it.
-        departing = (idx == jnp.argmin(tt)) & active & take_dep
-        x_new = jnp.where(departing | (active & (x_new <= tol)), 0.0, x_new)
-        newly_done = active & (x_new == 0.0)
-        times = jnp.where(newly_done, t_new, times)
-        i_new = jnp.searchsorted(arr, t_new, side="right").astype(i.dtype)
-        i_new = jnp.maximum(i, i_new)  # monotone even on no-op steps
-        st_new = srule.observe(
-            st, Observation(alloc=alloc, rate=rate, dt=dt, active=active)
-        )
-        out = (alloc, t, x) if record else None
-        if telemetry is None:
-            return (x_new, t_new, i_new, times, st_new), out
-        tel_new, tel_out = telemetry.step(
-            tel,
-            ProbeEvent(
-                t=t, dt=dt, alloc=alloc, rate=rate, active=active, x=x,
-                p=p_now, rule_state=st,
-            ),
-        )
-        return (x_new, t_new, i_new, times, st_new, tel_new), (out, tel_out)
+            x_new = jnp.where(active, x - dt * rate, x)
+            # The argmin job departs BY CONSTRUCTION when the departure is the
+            # next event; float residue (~eps*x) must not be allowed to keep it.
+            departing = (idx == jnp.argmin(tt)) & active & take_dep
+            x_new = jnp.where(departing | (active & (x_new <= tol)), 0.0, x_new)
+            newly_done = active & (x_new == 0.0)
+            times = jnp.where(newly_done, t_new, times)
+            i_new = jnp.searchsorted(arr, t_new, side="right").astype(i.dtype)
+            i_new = jnp.maximum(i, i_new)  # monotone even on no-op steps
+            st_new = srule.observe(
+                st, Observation(alloc=alloc, rate=rate, dt=dt, active=active)
+            )
+            out = (alloc, t, x) if record else None
+            if telemetry is None:
+                return (x_new, t_new, i_new, times, st_new), out
+            tel_new, tel_out = telemetry.step(
+                tel,
+                ProbeEvent(
+                    t=t, dt=dt, alloc=alloc, rate=rate, active=active, x=x,
+                    p=p_now, rule_state=st,
+                ),
+            )
+            return (x_new, t_new, i_new, times, st_new, tel_new), (out, tel_out)
 
     init = (xs, jnp.asarray(t0, dtype), i0, jnp.zeros(M, dtype), srule.init())
     if telemetry is not None:
@@ -670,44 +673,46 @@ def run_ranked(
 
     def body(carry, _):
         x, t, i, ranks, m, times = carry
-        theta = rank_policy(ranks, m, p, dtype=dtype)
-        rate = speedup(theta * n_servers, p)
-        # Next departure: the smallest active job, i.e. rank m, found by
-        # argmax since ranks are unique with maximum m (0 when inactive).
-        small = jnp.argmax(ranks)
-        has_active = m > 0
-        x_s = x[small]
-        r_s = rate[small]
-        dt_dep = jnp.where(has_active & (r_s > 0), x_s / r_s, jnp.inf)
-        t_next_arr = jnp.where(i < M, arr[jnp.minimum(i, M - 1)], jnp.inf)
-        dt_arr = jnp.maximum(t_next_arr - t, 0.0)
-        dt = jnp.minimum(dt_dep, dt_arr)
-        any_event = jnp.isfinite(dt)
-        dt = jnp.where(any_event, dt, 0.0)
-        admit = any_event & (dt_arr <= dt_dep)
-        take_dep = any_event & (dt_dep <= dt_arr)
-        t_new = jnp.where(admit, t_next_arr, t + dt)
-        active = ranks > 0
-        x_new = jnp.where(active, jnp.maximum(x - dt * rate, 0.0), x)
-        # Departure: drop rank m; every other active rank stays valid.
-        departing = (idx == small) & active & take_dep
-        x_new = jnp.where(departing, 0.0, x_new)
-        times = jnp.where(departing, t_new, times)
-        ranks = jnp.where(departing, 0, ranks)
-        m = m - jnp.where(take_dep & has_active, 1, 0)
-        # Arrival: insert job i at its rank among the (post-departure)
-        # active set; ties break by index, matching size_ranks_desc.
-        i_c = jnp.minimum(i, M - 1)
-        x_a = xs[i_c]
-        still = ranks > 0
-        ahead = still & ((x_new > x_a) | ((x_new == x_a) & (idx < i_c)))
-        r_a = 1 + jnp.sum(ahead, dtype=jnp.int32)
-        bumped = jnp.where(still & (ranks >= r_a), ranks + 1, ranks)
-        inserted = bumped.at[i_c].set(r_a)
-        ranks = jnp.where(admit, inserted, ranks)
-        m = m + jnp.where(admit, 1, 0)
-        i = i + jnp.where(admit, 1, 0)
-        return (x_new, t_new, i, ranks, m, times), None
+        with jax.named_scope("engine.allocate"):
+            theta = rank_policy(ranks, m, p, dtype=dtype)
+            rate = speedup(theta * n_servers, p)
+        with jax.named_scope("engine.advance"):
+            # Next departure: the smallest active job, i.e. rank m, found by
+            # argmax since ranks are unique with maximum m (0 when inactive).
+            small = jnp.argmax(ranks)
+            has_active = m > 0
+            x_s = x[small]
+            r_s = rate[small]
+            dt_dep = jnp.where(has_active & (r_s > 0), x_s / r_s, jnp.inf)
+            t_next_arr = jnp.where(i < M, arr[jnp.minimum(i, M - 1)], jnp.inf)
+            dt_arr = jnp.maximum(t_next_arr - t, 0.0)
+            dt = jnp.minimum(dt_dep, dt_arr)
+            any_event = jnp.isfinite(dt)
+            dt = jnp.where(any_event, dt, 0.0)
+            admit = any_event & (dt_arr <= dt_dep)
+            take_dep = any_event & (dt_dep <= dt_arr)
+            t_new = jnp.where(admit, t_next_arr, t + dt)
+            active = ranks > 0
+            x_new = jnp.where(active, jnp.maximum(x - dt * rate, 0.0), x)
+            # Departure: drop rank m; every other active rank stays valid.
+            departing = (idx == small) & active & take_dep
+            x_new = jnp.where(departing, 0.0, x_new)
+            times = jnp.where(departing, t_new, times)
+            ranks = jnp.where(departing, 0, ranks)
+            m = m - jnp.where(take_dep & has_active, 1, 0)
+            # Arrival: insert job i at its rank among the (post-departure)
+            # active set; ties break by index, matching size_ranks_desc.
+            i_c = jnp.minimum(i, M - 1)
+            x_a = xs[i_c]
+            still = ranks > 0
+            ahead = still & ((x_new > x_a) | ((x_new == x_a) & (idx < i_c)))
+            r_a = 1 + jnp.sum(ahead, dtype=jnp.int32)
+            bumped = jnp.where(still & (ranks >= r_a), ranks + 1, ranks)
+            inserted = bumped.at[i_c].set(r_a)
+            ranks = jnp.where(admit, inserted, ranks)
+            m = m + jnp.where(admit, 1, 0)
+            i = i + jnp.where(admit, 1, 0)
+            return (x_new, t_new, i, ranks, m, times), None
 
     init = (
         xs,

@@ -43,6 +43,7 @@ agreement with the pre-refactor outputs.
 from __future__ import annotations
 
 import functools
+import itertools
 import json
 import os
 import subprocess
@@ -403,6 +404,7 @@ def _cell_fn(spec: Sweep, name: str):
     golden-pin tests demand bit-for-bit f64 agreement with the
     pre-refactor sweeps.
     """
+    import jax
     import jax.numpy as jnp
 
     from repro.core.analysis import per_class_mean
@@ -437,6 +439,7 @@ def _cell_fn(spec: Sweep, name: str):
 
         return scalar_values(tel, spec.telemetry)
 
+    @jax.named_scope("engine.reduce")
     def metrics_of(res, scn):
         out = []
         for m in spec.metrics:
@@ -486,7 +489,8 @@ def _cell_fn(spec: Sweep, name: str):
         )
 
         def one(key, rate):
-            scn = sampler(key, spec.n_jobs, rate)
+            with jax.named_scope("engine.sample"):
+                scn = sampler(key, spec.n_jobs, rate)
             span = spec.n_jobs / rate  # expected arrival span at this rate
             window = (warm * span, end * span)
             probe = None
@@ -533,7 +537,8 @@ def _cell_fn(spec: Sweep, name: str):
         )
 
         def one(key, rate):
-            scn = sampler(key, spec.n_jobs, rate)
+            with jax.named_scope("engine.sample"):
+                scn = sampler(key, spec.n_jobs, rate)
             res = simulate_multiclass(
                 scn,
                 classes=spec.classes,
@@ -561,7 +566,8 @@ def _cell_fn(spec: Sweep, name: str):
         pol = make_policy(name, n_servers=spec.n_servers)
 
         def one(key, rate):
-            scn = sampler(key, spec.n_jobs, rate)
+            with jax.named_scope("engine.sample"):
+                scn = sampler(key, spec.n_jobs, rate)
             if spec.arm == "oracle":
                 # simulate_scenario shows the rule the CURRENT true regime.
                 res = simulate_scenario(
@@ -622,7 +628,8 @@ def _cell_fn(spec: Sweep, name: str):
     )
 
     def one(key, rate):
-        scn = sampler(key, spec.n_jobs, rate)
+        with jax.named_scope("engine.sample"):
+            scn = sampler(key, spec.n_jobs, rate)
         if spec.superstep:
             res = simulate_online_superstep(
                 scn.x0, scn.arrival_times, spec.p, spec.n_servers, name,
@@ -954,6 +961,8 @@ class SweepResult(NamedTuple):
 RUN_LOG: list[dict] = []
 RUN_LOG_MAX = 512
 
+_CALLS = itertools.count()  # request ids of run_sweep calls in this process
+
 
 def bench_records() -> list[dict]:
     return list(RUN_LOG)
@@ -994,35 +1003,40 @@ def run_sweep(
     import jax
     import jax.numpy as jnp
 
+    from repro import tracing
+
     if shard_axis not in ("seeds", "rates"):
         raise ValueError(f"shard_axis must be 'seeds' or 'rates', not {shard_axis!r}")
-    chunk = resolve_chunk(spec, chunk_seeds, max_jobs_in_flight)
-    keys = jax.random.split(jax.random.PRNGKey(spec.seed), spec.n_seeds)
-    rates = jnp.asarray(spec.rates, dtype=jnp.result_type(float))
+    call = next(_CALLS)
+    with tracing.span("sweep.prepare", request=call):
+        chunk = resolve_chunk(spec, chunk_seeds, max_jobs_in_flight)
+        keys = jax.random.split(jax.random.PRNGKey(spec.seed), spec.n_seeds)
+        rates = jnp.asarray(spec.rates, dtype=jnp.result_type(float))
 
-    n_dev = jax.device_count() if shard else 1
-    S = spec.n_seeds
-    R = len(spec.rates)
-    if shard and shard_axis == "rates":
-        # Pad the rate grid to the device count; padded rows are sliced off
-        # below.  Seeds stay whole per device.
-        r_pad = -(-R // n_dev) * n_dev
-        if r_pad > R:
-            rates = jnp.concatenate([rates, rates[:1].repeat(r_pad - R)])
-        if chunk is not None and chunk >= S:
-            chunk = None
-    else:
-        s_pad = -(-S // n_dev) * n_dev  # shard grid; chunk pads inside it
-        if s_pad > S:
-            keys = jnp.concatenate([keys, keys[:1].repeat(s_pad - S, axis=0)])
-        if chunk is not None and chunk >= s_pad // n_dev:
-            chunk = None  # one chunk == the plain vmap; share its executor
+        n_dev = jax.device_count() if shard else 1
+        S = spec.n_seeds
+        R = len(spec.rates)
+        if shard and shard_axis == "rates":
+            # Pad the rate grid to the device count; padded rows are sliced
+            # off below.  Seeds stay whole per device.
+            r_pad = -(-R // n_dev) * n_dev
+            if r_pad > R:
+                rates = jnp.concatenate([rates, rates[:1].repeat(r_pad - R)])
+            if chunk is not None and chunk >= S:
+                chunk = None
+        else:
+            s_pad = -(-S // n_dev) * n_dev  # shard grid; chunk pads inside it
+            if s_pad > S:
+                keys = jnp.concatenate([keys, keys[:1].repeat(s_pad - S, axis=0)])
+            if chunk is not None and chunk >= s_pad // n_dev:
+                chunk = None  # one chunk == the plain vmap; share its executor
 
     stats: dict[str, dict[str, np.ndarray]] = {}
     compile_s = 0.0
     wall_s = 0.0
     for step, name in enumerate(spec.policies):
-        f, c_s = _executor(spec, name, keys, rates, chunk, shard, shard_axis)
+        with tracing.span("sweep.executor", request=call):
+            f, c_s = _executor(spec, name, keys, rates, chunk, shard, shard_axis)
         compile_s += c_s
         t0 = time.perf_counter()
         # StepTraceAnnotation is a no-op unless a jax.profiler trace is
@@ -1032,8 +1046,19 @@ def run_sweep(
         with jax.profiler.StepTraceAnnotation(
             "run_sweep", step_num=step, policy=name, scenario=spec.scenario
         ):
-            out = f(keys, rates)
-            out = tuple(np.asarray(a) for a in out)  # blocks until ready
+            with tracing.span("sweep.dispatch", request=call) as traced:
+                out = f(keys, rates)
+            if traced:
+                # Split the wait from the copies only when it is recorded.
+                # The copies are queued first, as np.asarray alone queues
+                # them: a copy asked for after the wait would cost one more
+                # round trip between host and device per call.
+                for a in out:
+                    a.copy_to_host_async()
+                with tracing.span("sweep.wait", request=call):
+                    jax.block_until_ready(out)
+            with tracing.span("sweep.fetch", request=call):
+                out = tuple(np.asarray(a) for a in out)
         wall_s += time.perf_counter() - t0
         stats[name] = {
             m: a[:R, :S] for m, a in zip(_out_names(spec), out, strict=True)

@@ -38,20 +38,33 @@ jobs through ``report_progress``.
 from __future__ import annotations
 
 import functools
+import itertools
 from dataclasses import dataclass, field
 
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.core.policies import make_policy
 from repro.sched.estimator import SpeedupEstimator, blended_p, pooled_p_hat
 from repro.sched.quantize import quantize_allocation, snap_to_slices
 
+_DECISIONS = itertools.count()  # request ids of allocations() in this process
+
 
 @functools.partial(jax.jit, static_argnames="name")
-def _policy_theta(name: str, x, p, n_servers, alpha):
+def _policy_core(name: str, x, p, n_servers, alpha):
     """One compiled policy call per (policy, padded length)."""
     return make_policy(name, n_servers=n_servers, alpha=alpha)(x, p)
+
+
+def _policy_theta(name: str, x, p, n_servers, alpha) -> np.ndarray:
+    """The compiled policy on device sizes ``x``, its shares back on the
+    host in float64."""
+    with tracing.span("sched.call"):
+        theta = _policy_core(name, x, p, n_servers, alpha)
+    with tracing.span("sched.fetch"):
+        return np.asarray(theta, dtype=np.float64)
 
 
 @dataclass
@@ -216,40 +229,48 @@ class ClusterScheduler:
         when quantizing, fractional chips when ``quantize=False``)."""
         import jax.numpy as jnp
 
-        act = self.active_jobs()
-        if not act:
-            return {}
-        p = self.effective_p()
-        if self.class_aware:
-            theta = self._class_theta(act)
-        else:
-            # The active count changes at nearly every epoch.  Padding the
-            # sizes to a power of two with zeros, which every policy treats
-            # as inactive (as the engine's scan does), lets the compiled
-            # policy be reused instead of recompiled at each new count.
-            rem = [j.remaining for j in act]
-            xp = np.zeros(max(8, 1 << (len(act) - 1).bit_length()))
-            xp[: len(act)] = rem
-            theta = np.asarray(_policy_theta(
-                self.policy_name.lower(), jnp.asarray(xp), p,
-                float(self.n_chips),
-                float(np.median(rem) * p / self.n_chips),
-            ), dtype=np.float64)[: len(act)]
-        if self.quantize:
-            chips = quantize_allocation(theta, self.n_chips, min_chips=self.min_chips)
-            if self.snap_slices:
-                chips = snap_to_slices(chips, self.n_chips)
-            chips = [int(c) for c in chips]
-        else:
-            chips = [float(c) for c in theta * self.n_chips]
-        out = {}
-        for j, c in zip(act, chips, strict=True):
-            j.chips = c
-            out[j.job_id] = c
-        self.events.append(
-            {"t": self.time, "event": "allocate", "chips": dict(out), "p": p}
-        )
-        return out
+        with tracing.span("sched.decision", request=next(_DECISIONS)):
+            with tracing.span("sched.table"):
+                act = self.active_jobs()
+                if not act:
+                    return {}
+                p = self.effective_p()
+                if not self.class_aware:
+                    # The active count changes at nearly every epoch.
+                    # Padding the sizes to a power of two with zeros, which
+                    # every policy treats as inactive (as the engine's scan
+                    # does), lets the compiled policy be reused instead of
+                    # recompiled at each new count.
+                    rem = [j.remaining for j in act]
+                    xp = np.zeros(max(8, 1 << (len(act) - 1).bit_length()))
+                    xp[: len(act)] = rem
+                    alpha = float(np.median(rem) * p / self.n_chips)
+            if self.class_aware:
+                with tracing.span("sched.call"):
+                    theta = self._class_theta(act)
+            else:
+                with tracing.span("sched.put"):
+                    x = jnp.asarray(xp)
+                theta = _policy_theta(self.policy_name.lower(), x, p,
+                                      float(self.n_chips), alpha)[: len(act)]
+            with tracing.span("sched.quantize"):
+                if self.quantize:
+                    chips = quantize_allocation(theta, self.n_chips,
+                                                min_chips=self.min_chips)
+                    if self.snap_slices:
+                        chips = snap_to_slices(chips, self.n_chips)
+                    chips = [int(c) for c in chips]
+                else:
+                    chips = [float(c) for c in theta * self.n_chips]
+            with tracing.span("sched.commit"):
+                out = {}
+                for j, c in zip(act, chips, strict=True):
+                    j.chips = c
+                    out[j.job_id] = c
+                self.events.append(
+                    {"t": self.time, "event": "allocate", "chips": dict(out), "p": p}
+                )
+            return out
 
     # --------------------------------------------------------- progress I/O
     def report_progress(self, job_id: str, work_done: float,
