@@ -8,12 +8,14 @@ optimization trajectory this repo shipped:
   ========  =========  =================================================
   variant   sorts/ev   what it is
   ========  =========  =================================================
-  seed          4      policy sort + the first quantizer port (separate
-                       trim and leftover argsorts), reconstructed here so
-                       the win stays attributable after the code moved on
-  unfused       3      policy sort + collapsed quantizer — what
-                       ``engine.quantized_rule`` ships today
-  fused         2      ``kernels/alloc.py`` ref pass sharing one sorted
+  seed          5      policy sorts + the first quantizer port (separate
+                       trim and leftover argsorts, scatter inverses),
+                       reconstructed here so the win stays attributable
+                       after the code moved on
+  unfused       4      policy sorts (size order and its inverse) +
+                       collapsed quantizer — what ``engine.quantized_rule``
+                       ships today
+  fused         3      ``kernels/alloc.py`` ref pass sharing one sorted
                        order (rank-space oversubscription cut)
   pallas        0      the Pallas kernel: O(M^2) comparison counting, no
                        sort primitive at all (interpret mode on CPU, so
@@ -52,16 +54,19 @@ import numpy as np
 # ------------------------------------------------- the seed's 3-sort quantizer
 def _seed_quantize(theta, n_chips: int, *, min_chips: int = 1):
     """The first ``quantize_allocation_jax`` port: separate trim/leftover
-    argsorts (3 sorts per call).  Kept verbatim here — not in core — purely
-    so the profiler can measure the collapse against its true baseline.
+    argsorts (3 sorts per call), each inverted by a scatter.  Kept verbatim
+    here — not in core — purely so the profiler can measure the collapse
+    against its true baseline.
     """
     import jax
     import jax.numpy as jnp
 
-    from repro.core.ranking import inv_rank
-
     theta = jnp.asarray(theta)
     M = theta.shape[0]
+
+    def inv_rank(order):
+        return jnp.zeros(M, jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
+
     if n_chips <= 0 or min_chips <= 0 or M == 0:
         return jnp.zeros(M, jnp.int32)
     cap = n_chips // min_chips

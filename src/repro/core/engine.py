@@ -60,7 +60,7 @@ import jax.numpy as jnp
 
 from repro.core.flowtime import speedup
 from repro.core.policies import Policy, equi, hesrpt, knee, srpt
-from repro.core.ranking import inv_rank
+from repro.core.ranking import in_stable_prefix
 
 # (x_active, p) -> (alloc, rate); ``alloc`` is theta for continuous rules
 # and integer chips for quantized rules, ``rate`` the per-job service rate.
@@ -288,7 +288,7 @@ def quantized_rule(
 
     For the heSRPT policy the returned rule carries a ``fused_variant``
     attribute: the ``kernels/alloc.py`` fused rank -> theta -> chips pass
-    (2 sorts per event instead of 3 on CPU, 0 on TPU), chip-exact vs this
+    (3 sorts per event instead of 4 on CPU, 0 on TPU), chip-exact vs this
     rule, selected by :func:`run`'s ``fused=True``.
     """
 
@@ -1291,7 +1291,10 @@ def quantize_allocation_jax(
     means ``K == 0`` and nothing was removed), so one argsort on a
     conditionally-selected key serves both — two sorts per call, not the
     three the first port paid.  Tie-breaking is unchanged: each branch
-    sorts the exact key (and stable order) it sorted before.
+    sorts the exact key (and stable order) it sorted before.  Every
+    position test ("among the first k of the sorted order") is
+    :func:`~repro.core.ranking.in_stable_prefix`, so the quantizer holds no
+    inverse permutation and pays no scatter.
 
     ``n_chips``/``min_chips`` are static Python ints.  Returns int32 chips.
     """
@@ -1305,8 +1308,8 @@ def quantize_allocation_jax(
     n_active = jnp.sum(active0, dtype=jnp.int32)
     # Oversubscribed: serve the largest-theta jobs (stable on ties), queue
     # the rest with 0, renormalize — the oracle's single recursion, unrolled.
-    desc = inv_rank(jnp.argsort(jnp.where(active0, -theta, jnp.inf)))
-    servable = active0 & (desc < cap)
+    key0 = jnp.where(active0, -theta, jnp.inf)
+    servable = active0 & in_stable_prefix(key0, jnp.argsort(key0), cap)
     over = n_active * min_chips > n_chips
     sub = jnp.where(servable, theta, 0.0)
     tot = jnp.sum(sub)
@@ -1345,13 +1348,13 @@ def quantize_allocation_jax(
     key = jnp.where(
         trim, jnp.where(elig, frac, jnp.inf), jnp.where(active, -frac, jnp.inf)
     )
-    pos = inv_rank(jnp.argsort(key))
-    extra = (elig & (pos < extra_needed)).astype(jnp.int32)
+    order = jnp.argsort(key)
+    extra = (elig & in_stable_prefix(key, order, extra_needed)).astype(jnp.int32)
     base = base - full - extra
 
     # Leftover chips (only when no trim happened): largest fracs first.
     remainder = n_chips - jnp.sum(base)
-    base = base + (active & (pos < remainder)).astype(jnp.int32)
+    base = base + (active & in_stable_prefix(key, order, remainder)).astype(jnp.int32)
     return base
 
 

@@ -40,7 +40,7 @@ def size_ranks_desc(x: jax.Array) -> jax.Array:
     broken by index (stable argsort), which is WLOG optimal by symmetry.
     """
     # Inactive jobs sort last (key = -inf after negation -> +inf); the
-    # order -> rank conversion is the shared inverse-permutation scatter.
+    # order -> rank conversion is the shared inverse permutation.
     return ranks_from_order(size_order_desc(x), _active(x))
 
 

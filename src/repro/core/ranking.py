@@ -1,12 +1,18 @@
-"""Shared inverse-permutation / ranking helpers.
+"""Shared sort-order helpers: ranks and prefix tests from an argsort.
 
-Three modules used to carry their own copy of the same two-line scatter
-(``engine._inv_rank``, ``policies.size_ranks_desc``'s rank scatter and
-``policies.weighted_hesrpt``'s inline inverse permutation).  They live here
-now — a leaf module importable by both ``core.policies`` and
-``core.engine`` (policies cannot import engine: engine imports policies)
-and by ``kernels.alloc``, whose fused allocation path must produce
-bit-identical ranks to the unfused one.
+Three modules used to carry their own copy of the same inverse
+permutation (``engine._inv_rank``, ``policies.size_ranks_desc``'s ranks and
+``policies.weighted_hesrpt``'s inline inverse).  They live here now — a
+leaf module importable by both ``core.policies`` and ``core.engine``
+(policies cannot import engine: engine imports policies) and by
+``kernels.alloc``, whose fused allocation path must produce bit-identical
+ranks to the unfused one.
+
+No helper scatters.  A batched scatter of M updates runs one update at a
+time on a TPU v5e (~4.6 ns each at M = 1000, 11x the sort it followed), so an
+inverse permutation is a second argsort, and a quantizer that only asks
+"is this job among the first k" uses :func:`in_stable_prefix`, which needs
+no inverse at all.
 """
 
 from __future__ import annotations
@@ -19,13 +25,27 @@ def inv_rank(order: jax.Array) -> jax.Array:
     """Position of each element in its own argsort (the inverse permutation).
 
     ``inv_rank(jnp.argsort(key))[i]`` is the 0-based position job ``i``
-    takes when sorted by ``key`` — the scatter form is O(M) where a second
-    argsort would pay another O(M log M) sort.
+    takes when sorted by ``key``.  It is the argsort of the permutation:
+    its keys are unique, so stability does not matter, and the int32
+    result equals the scatter ``zeros.at[order].set(arange)``.
     """
-    M = order.shape[0]
-    return (
-        jnp.zeros(M, jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
-    )
+    return jnp.argsort(order).astype(jnp.int32)
+
+
+def in_stable_prefix(key: jax.Array, order: jax.Array, k) -> jax.Array:
+    """``inv_rank(order) < k`` for a stable argsort ``order`` of ``key``.
+
+    Element ``i`` is among the first ``k`` iff ``k > 0`` and ``(key[i], i)
+    <= (key[j], j)`` lexicographically, where ``j = order[k - 1]`` (clipped
+    into range, so ``k >= M`` takes every element).  One gather and an
+    O(M) compare, no inverse permutation.  Exact for keys without NaN:
+    ``<`` and ``==`` agree with the sort's comparator on ±0.0 and ±inf.
+    """
+    M = key.shape[0]
+    j = order[jnp.clip(k - 1, 0, M - 1)]
+    kj = key[j]
+    idx = jnp.arange(M, dtype=order.dtype)
+    return (k > 0) & ((key < kj) | ((key == kj) & (idx <= j)))
 
 
 def size_order_desc(x: jax.Array) -> jax.Array:
@@ -44,8 +64,7 @@ def size_order_desc(x: jax.Array) -> jax.Array:
 def ranks_from_order(order: jax.Array, active: jax.Array) -> jax.Array:
     """1-based ranks from a :func:`size_order_desc` order (0 = inactive).
 
-    Bit-identical to the historical ``size_ranks_desc`` scatter: the
-    largest active job gets rank 1, the smallest rank ``m``; every rank is
-    ``inv_rank + 1`` masked to the active set.
+    The largest active job gets rank 1, the smallest rank ``m``; every
+    rank is ``inv_rank + 1`` masked to the active set.
     """
     return jnp.where(active, inv_rank(order) + 1, 0)

@@ -16,8 +16,9 @@ largest-remainder round.  For heSRPT both re-derivations are redundant:
   conditionally-selected key serves both (the same collapse
   ``quantize_allocation_jax`` itself now uses).
 
-``hesrpt_alloc_fused_ref`` is that algorithm in pure jnp: **2 argsorts per
-event** (sizes + fractional parts) where the unfused rule pays 3, exact vs
+``hesrpt_alloc_fused_ref`` is that algorithm in pure jnp: **3 argsorts per
+event** (sizes, the ranks' inverse permutation, fractional parts) where the
+unfused rule pays 4, and no scatter, exact vs
 ``policies.hesrpt`` + ``engine.quantize_allocation_jax`` by construction —
 every floating-point sum runs over the original index order, every integer
 step is order-independent, and the one shared sort uses the exact keys and
@@ -57,7 +58,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.policies import hesrpt, hesrpt_theta_from_ranks
-from repro.core.ranking import inv_rank, ranks_from_order, size_order_desc
+from repro.core.ranking import in_stable_prefix, ranks_from_order, size_order_desc
 
 IMPLS = ("auto", "ref", "pallas", "interpret")
 
@@ -129,12 +130,12 @@ def _quantize_from_ranks(
     key = jnp.where(
         trim, jnp.where(elig, frac, jnp.inf), jnp.where(active, -frac, jnp.inf)
     )
-    pos = inv_rank(jnp.argsort(key))
-    extra = (elig & (pos < extra_needed)).astype(jnp.int32)
+    order = jnp.argsort(key)
+    extra = (elig & in_stable_prefix(key, order, extra_needed)).astype(jnp.int32)
     base = base - full - extra
 
     remainder = n_chips - jnp.sum(base)
-    base = base + (active & (pos < remainder)).astype(jnp.int32)
+    base = base + (active & in_stable_prefix(key, order, remainder)).astype(jnp.int32)
     return base
 
 

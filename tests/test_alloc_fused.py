@@ -13,8 +13,9 @@
   sweep output (the same array tests/test_sweeps.py pins for the unfused
   path) — the fused engine changes the op schedule, never the numbers.
 - **Sort counts**: the optimization's whole point, measured from compiled
-  HLO via ``launch.hlo_analysis.op_histogram`` — 1 sort for the policy,
-  3 for the unfused allocate, 2 fused, 0 for the Pallas kernel, and the
+  HLO via ``launch.hlo_analysis.op_histogram`` — 2 sorts for the policy
+  (the size order and its inverse permutation), 4 for the unfused
+  allocate, 3 fused, 0 for the Pallas kernel, and the
   engine's scan body pays exactly one fewer sort per event when fused.
 
 Hypothesis twins of the quantizer invariants (conservation, min-chips
@@ -189,20 +190,20 @@ def _sorts(f, *args) -> float:
 
 
 def test_sort_counts_measured_from_hlo():
-    """The collapse, in compiled-HLO sort ops: policy 1, unfused allocate 3,
-    fused ref 2, Pallas kernel 0."""
+    """The collapse, in compiled-HLO sort ops: policy 2, unfused allocate 4,
+    fused ref 3, Pallas kernel 0."""
     from repro.kernels.alloc import hesrpt_alloc_fused_ref
 
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.pareto(1.5, 64) + 1.0)
-    assert _sorts(hesrpt, x, 0.5) == 1
+    assert _sorts(hesrpt, x, 0.5) == 2
     assert _sorts(
         lambda xv, pv: engine.quantize_allocation_jax(hesrpt(xv, pv), 16),
         x, 0.5,
-    ) == 3
+    ) == 4
     assert _sorts(
         lambda xv, pv: hesrpt_alloc_fused_ref(xv, pv, 16)[1], x, 0.5
-    ) == 2
+    ) == 3
     assert _sorts(
         lambda xv, pv: hesrpt_alloc_fused(xv, pv, 16, impl="interpret")[1],
         x, 0.5,
@@ -210,8 +211,8 @@ def test_sort_counts_measured_from_hlo():
 
 
 def test_engine_scan_pays_one_fewer_sort_per_event_fused():
-    """Trip-count-aware histogram of the compiled scan: 3 sorts/event
-    unfused vs 2 fused (+1 one-time arrival-order sort outside the loop)."""
+    """Trip-count-aware histogram of the compiled scan: 4 sorts/event
+    unfused vs 3 fused (+1 one-time arrival-order sort outside the loop)."""
     m = 16
     x0, arr = _stream(m, seed=2)
     rule = engine.quantized_rule(hesrpt, 16, dtype=jnp.float64)
@@ -224,8 +225,8 @@ def test_engine_scan_pays_one_fewer_sort_per_event_fused():
 
         return _sorts(f, x0, arr)
 
-    assert scan_sorts(False) == 1 + 3 * m
-    assert scan_sorts(True) == 1 + 2 * m
+    assert scan_sorts(False) == 1 + 4 * m
+    assert scan_sorts(True) == 1 + 3 * m
 
 
 # ------------------------------------- quantizer invariants, fused kernel

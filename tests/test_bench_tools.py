@@ -151,5 +151,5 @@ def test_profiler_sort_count_helper():
     from repro.core.policies import hesrpt
 
     x = jnp.asarray(np.random.default_rng(0).pareto(1.5, 32) + 1.0)
-    assert _sort_count(hesrpt, x, 0.5) == 1
+    assert _sort_count(hesrpt, x, 0.5) == 2
     assert _sort_count(lambda v: v * 2.0, x) == 0

@@ -184,19 +184,34 @@ def _op_names(text, opcode):
             for m in [re.search(r'op_name="([^"]*)"', line)] if m]
 
 
-def test_whole_chip_executor_sorts_and_scatters_sit_under_engine_allocate():
+@pytest.fixture(scope="module")
+def whole_chip_executor():
+    """The whole-chip heSRPT sweep executor: compiled text, and the lowered
+    text with every op's location (before fusion)."""
     spec = Sweep.create(("hesrpt",), (4.0,), n_jobs=16, n_seeds=2, p=0.5,
                         n_servers=64.0, n_chips=64)
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
     lowered = jax.jit(sweeps._build_fn(spec, "hesrpt", None, False)).lower(
         keys, jnp.asarray(spec.rates))
-    text = lowered.compile().as_text()
+    return lowered.compile().as_text(), lowered.as_text(debug_info=True)
+
+
+def test_whole_chip_executor_sorts_and_scatters_sit_under_engine_allocate(
+        whole_chip_executor):
+    text, source = whole_chip_executor
     in_scan = [n for op in ("sort", "scatter") for n in _op_names(text, op)
                if "/while/body/" in n]
     assert in_scan and all("engine.allocate" in n for n in in_scan)
-    source = lowered.as_text(debug_info=True)  # every op's location, before fusion
     for scope in ("engine.advance", "engine.sample", "engine.reduce"):
         assert re.search(rf'["/(]{scope}[/)"]', source), scope
+
+
+def test_whole_chip_executor_event_loop_has_no_scatter(whole_chip_executor):
+    text, _ = whole_chip_executor
+    in_loop = {op: [n for n in _op_names(text, op) if "/while/body/" in n]
+               for op in ("sort", "scatter")}
+    assert not in_loop["scatter"]
+    assert in_loop["sort"] and all("engine.allocate" in n for n in in_loop["sort"])
 
 
 def test_ranked_scan_allocate_and_advance_scopes():
