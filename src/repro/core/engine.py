@@ -190,6 +190,8 @@ def finish_alloc(
     snap_slices: bool = False,
     slices: tuple[int, ...] = DEFAULT_SLICES,
     dtype,
+    lo: jax.Array | None = None,
+    hi: jax.Array | None = None,
 ):
     """The ONE ``theta -> (alloc, rate)`` tail every allocation rule shares.
 
@@ -197,7 +199,10 @@ def finish_alloc(
     itself and the rate is ``s(theta * n_alloc)``.  Whole-chips regime:
     largest-remainder rounding (:func:`quantize_allocation_jax`) with a
     ``min_chips`` floor, optionally snapped to power-of-two ICI slices
-    (:func:`snap_to_slices_jax`), rate ``s(chips)``.  Centralized so the
+    (:func:`snap_to_slices_jax`), rate ``s(chips)``.  Per-job width limits
+    ``lo``/``hi`` (slice sizes, in the engine's arrival-sorted order) take
+    the capped rounding and bound the snap's upgrades; without them the
+    program is the limit-free one.  Centralized so the
     stateless rules here, :func:`knee_rule`, the class-aware rules
     (``core/multiclass.py``) and the estimating rules
     (``core/estimation.py``) cannot desynchronize on quantization order or
@@ -206,9 +211,10 @@ def finish_alloc(
     theta = theta.astype(dtype)
     if n_chips is None:
         return theta, speedup(theta * n_alloc, p)
-    chips = quantize_allocation_jax(theta, n_chips, min_chips=min_chips)
+    chips = quantize_allocation_jax(theta, n_chips, min_chips=min_chips, lo=lo, hi=hi)
     if snap_slices:
-        chips = snap_to_slices_jax(chips, n_chips, slices=slices)
+        with jax.named_scope("engine.snap"):
+            chips = snap_to_slices_jax(chips, n_chips, slices=slices, hi=hi)
     return chips, speedup(chips.astype(dtype), p)
 
 
@@ -1261,7 +1267,12 @@ def run_stream_ranked(
 
 # -------------------------------------------------- JAX-native quantization
 def quantize_allocation_jax(
-    theta: jax.Array, n_chips: int, *, min_chips: int = 1
+    theta: jax.Array,
+    n_chips: int,
+    *,
+    min_chips: int = 1,
+    lo: jax.Array | None = None,
+    hi: jax.Array | None = None,
 ) -> jax.Array:
     """Vectorized-jnp port of ``sched.quantize.quantize_allocation``.
 
@@ -1296,12 +1307,20 @@ def quantize_allocation_jax(
     :func:`~repro.core.ranking.in_stable_prefix`, so the quantizer holds no
     inverse permutation and pays no scatter.
 
+    Per-job width limits ``lo``/``hi`` (int arrays, either may be None:
+    ``lo`` then defaults to ``min_chips``, ``hi`` to ``n_chips``) take
+    :func:`_quantize_capped` instead; with both None this is the
+    limit-free program, decided here in Python.
+
     ``n_chips``/``min_chips`` are static Python ints.  Returns int32 chips.
     """
     theta = jnp.asarray(theta)
     M = theta.shape[0]
     if n_chips <= 0 or min_chips <= 0 or M == 0:
         return jnp.zeros(M, jnp.int32)
+    if lo is not None or hi is not None:
+        with jax.named_scope("engine.cap"):
+            return _quantize_capped(theta, n_chips, min_chips, lo, hi)
     cap = n_chips // min_chips  # most jobs the floor allows us to serve
 
     active0 = theta > 0
@@ -1320,11 +1339,18 @@ def quantize_allocation_jax(
     fl = jnp.floor(raw)
     frac = raw - fl
     base = jnp.where(active, jnp.maximum(fl, min_chips), 0.0).astype(jnp.int32)
+    return _trim_and_fill(base, frac, active, min_chips, n_chips)
 
+
+def _trim_and_fill(base, frac, active, floor, n_chips: int, room=None):
+    """The largest-remainder tail shared by both quantizers: trim an
+    overflowing floor from the largest holdings (never below ``floor``, a
+    Python int or a per-job array), else hand the leftover chips to the
+    largest fractional parts among ``room`` (default: every active job)."""
     # Min-chips floor oversubscribed the pool: trim K chips from the
     # largest holdings, exactly as the oracle's greedy (see docstring).
     K = jnp.maximum(jnp.sum(base) - n_chips, 0)
-    capj = jnp.maximum(base - min_chips, 0) * (base > min_chips)
+    capj = jnp.maximum(base - floor, 0) * (base > floor)
 
     def bisect(_, lohi):
         lo, hi = lohi
@@ -1345,8 +1371,9 @@ def quantize_allocation_jax(
     # frac among active jobs, only reachable when K == 0) — the branches
     # are mutually exclusive, see the docstring.
     trim = K > 0
+    room = active if room is None else room
     key = jnp.where(
-        trim, jnp.where(elig, frac, jnp.inf), jnp.where(active, -frac, jnp.inf)
+        trim, jnp.where(elig, frac, jnp.inf), jnp.where(room, -frac, jnp.inf)
     )
     order = jnp.argsort(key)
     extra = (elig & in_stable_prefix(key, order, extra_needed)).astype(jnp.int32)
@@ -1354,12 +1381,95 @@ def quantize_allocation_jax(
 
     # Leftover chips (only when no trim happened): largest fracs first.
     remainder = n_chips - jnp.sum(base)
-    base = base + (active & in_stable_prefix(key, order, remainder)).astype(jnp.int32)
+    base = base + (room & in_stable_prefix(key, order, remainder)).astype(jnp.int32)
     return base
 
 
+def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi):
+    """Whole chips within per-job width limits ``lo <= chips <= hi``.
+
+    1. **Admission**: the longest prefix of the active jobs by descending
+       ``theta`` (stable) whose ``lo`` fit in ``n_chips`` is served, the
+       rest queued at 0, and ``theta`` renormalized over the served jobs
+       when any was queued (with a uniform ``lo`` this is the limit-free
+       quantizer's oversubscription rule).
+    2. **Capped water-fill**: ``raw = min(lam * theta * n_chips, hi)`` with
+       the ``lam`` that makes ``raw`` sum to ``n_chips`` (``lam >= 1`` for
+       a ``theta`` summing to 1; every job at ``hi`` when the ``hi`` sum to
+       less).  Job ``j`` caps at ``lam = k_j = hi_j / (theta_j *
+       n_chips)``; the capped jobs are a prefix of the ``k`` order, and job
+       ``c`` of that order is capped iff ``f(k_c) = sum_{j<c} hi_j + k_c *
+       sum_{j>=c} theta_j n_chips`` is still short of ``n_chips``.  One
+       sort and two cumulative sums, no iteration.  Where no cap binds,
+       ``raw`` is ``theta * n_chips`` itself.
+    3. **Rounding**: ``base = clip(floor(raw), lo, hi)``, then the
+       limit-free largest-remainder tail with the trim floor at ``lo`` and
+       leftover chips only to jobs below ``hi``.
+
+    Three sorts (admission, cap order, fractional parts), each carrying
+    what it orders as sort operands: no permutation gather.  The chips sum
+    to at most ``n_chips`` (less only when the served jobs' ``hi`` sum to
+    less).  Returns int32 chips.
+    """
+    M = theta.shape[0]
+    lo = jnp.broadcast_to(
+        jnp.asarray(min_chips if lo is None else lo, jnp.int32), (M,))
+    hi = jnp.broadcast_to(
+        jnp.asarray(n_chips if hi is None else hi, jnp.int32), (M,))
+
+    idx = jnp.arange(M, dtype=jnp.int32)
+
+    def sort_by(key, *payload):
+        # A stable sort that carries its payload: the sorted key, the argsort
+        # order, and the payload in that order without a gather (a
+        # permutation gather runs one element at a time on a TPU v5e, as a
+        # scatter does).
+        return jax.lax.sort((key, idx, *payload), num_keys=1, is_stable=True)
+
+    active0 = theta > 0
+    key0 = jnp.where(active0, -theta, jnp.inf)
+    _, order0, lo_s = sort_by(key0, jnp.where(active0, lo, 0))
+    # At most n_chips jobs can be served (every lo >= 1): a short prefix.
+    need = jnp.cumsum(lo_s[: min(M, n_chips)])
+    served = active0 & in_stable_prefix(key0, order0, jnp.sum(need <= n_chips))
+    sub = jnp.where(served, theta, 0.0)
+    tot = jnp.sum(sub)
+    over = jnp.sum(jnp.where(active0, lo, 0)) > n_chips
+    theta_eff = jnp.where(over, jnp.where(tot > 0, sub / tot, 0.0), theta)
+    active = theta_eff > 0
+
+    t_n = theta_eff * n_chips
+    hi_f = hi.astype(t_n.dtype)
+    k = jnp.where(active, hi_f / jnp.where(active, t_n, 1.0), jnp.inf)
+    k_s, order1, hi_s, tn_s = sort_by(
+        k, jnp.where(active, hi_f, 0.0), jnp.where(active, t_n, 0.0))
+    # Fewer than n_chips jobs can cap (every hi >= 1), so only that prefix
+    # of the cap order is tested.  The shares from position c on are a
+    # suffix sum, not a total less a prefix: that difference cancels below
+    # zero in float32 where the last shares are tiny.
+    c = min(M, n_chips)
+    hi_before = jnp.cumsum(hi_s[:c]) - hi_s[:c]  # whole numbers: exact
+    tn_from = jnp.cumsum(tn_s[:c][::-1])[::-1] + jnp.sum(tn_s[c:])
+    short = jnp.isfinite(k_s[:c]) & (hi_before + k_s[:c] * tn_from < n_chips)
+    n_capped = jnp.min(jnp.where(short, c, idx[:c]))  # leading run
+    capped = active & in_stable_prefix(k, order1, n_capped)
+    free = n_chips - jnp.sum(jnp.where(capped, hi_f, 0.0))
+    rest = jnp.sum(jnp.where(active & ~capped, t_n, 0.0))
+    scale = jnp.where(rest > 0, free / jnp.where(rest > 0, rest, 1.0), 0.0)
+    raw = jnp.where(capped, hi_f, jnp.where(jnp.any(capped), t_n * scale, t_n))
+
+    fl = jnp.floor(raw)
+    frac = raw - fl
+    base = jnp.where(active, jnp.clip(fl, lo, hi), 0.0).astype(jnp.int32)
+    return _trim_and_fill(base, frac, active, lo, n_chips, room=active & (base < hi))
+
+
 def snap_to_slices_jax(
-    chips: jax.Array, n_chips: int, *, slices: tuple[int, ...] = DEFAULT_SLICES
+    chips: jax.Array,
+    n_chips: int,
+    *,
+    slices: tuple[int, ...] = DEFAULT_SLICES,
+    hi: jax.Array | None = None,
 ) -> jax.Array:
     """Vectorized-jnp port of ``sched.quantize.snap_to_slices``.
 
@@ -1370,40 +1480,45 @@ def snap_to_slices_jax(
     non-negative, upgrade the job with the largest lost allocation (ties
     break toward the higher index, matching the oracle's ``>=`` scan).  The
     leftover pool strictly shrinks every round, so the ``while_loop`` is
-    bounded by ``n_chips`` iterations.
+    bounded by ``n_chips`` iterations.  A per-job ceiling ``hi`` makes an
+    upgrade eligible only while the next slice is at most ``hi``; snapping
+    down keeps a job at or above a floor that is itself a slice.
 
     ``n_chips``/``slices`` are static; returns int32 chips.  Exact
     agreement with the NumPy oracle is property-tested in
-    tests/test_quantize.py.
+    tests/test_quantize.py and, with ceilings, tests/test_width_limits.py.
     """
     sl = jnp.asarray(sorted(slices), jnp.int32)
-    S = sl.shape[0]
     chips0 = jnp.asarray(chips).astype(jnp.int32)
     M = chips0.shape[0]
     if M == 0:
         return chips0
     idx = jnp.arange(M, dtype=jnp.int32)
+    none = jnp.iinfo(jnp.int32).max
 
+    # Slices by comparison against each static size, and the pick by a
+    # mask: no searchsorted, gather or scatter, which run one element at a
+    # time on a TPU v5e, inside a loop that runs once per upgrade.
     # Snap down: largest slice <= count (0 when count < slices[0]).
-    down = jnp.searchsorted(sl, chips0, side="right") - 1
-    snapped0 = jnp.where(down >= 0, sl[jnp.maximum(down, 0)], 0)
+    snapped0 = jnp.max(jnp.where(sl <= chips0[:, None], sl, 0), axis=1)
     left0 = jnp.int32(n_chips) - jnp.sum(snapped0)
 
     def candidate(snapped, left):
-        nxt_i = jnp.searchsorted(sl, snapped, side="right")
-        nxt = sl[jnp.minimum(nxt_i, S - 1)]
+        nxt = jnp.min(jnp.where(sl > snapped[:, None], sl, none), axis=1)
         step = nxt - snapped
         lost = chips0 - snapped
         elig = (
-            (nxt_i < S)
+            (nxt < none)
             & (step <= left)
             & (lost >= 0)
             & ~((snapped == 0) & (chips0 == 0))
         )
+        if hi is not None:
+            elig = elig & (nxt <= hi)
         # Max lost, ties to the highest index — the oracle's `>=` scan.
         key = jnp.where(elig, lost * M + idx, -1)
-        j = jnp.argmax(key)
-        return j, nxt[j], step[j], key[j] >= 0
+        pick = idx == jnp.argmax(key)
+        return pick, nxt, jnp.sum(jnp.where(pick, step, 0)), jnp.max(key) >= 0
 
     # The chosen candidate rides in the carry so each round computes it
     # once (the next candidate is derived at the end of body, not re-done
@@ -1413,8 +1528,8 @@ def snap_to_slices_jax(
         return any_elig & (left > 0)
 
     def body(state):
-        snapped, left, j, nxt_j, step_j, _ = state
-        snapped = snapped.at[j].set(nxt_j)
+        snapped, left, pick, nxt, step_j, _ = state
+        snapped = jnp.where(pick, nxt, snapped)
         left = left - step_j
         return (snapped, left, *candidate(snapped, left))
 

@@ -83,6 +83,8 @@ class ClassSpec(NamedTuple):
     size_scale: float = 1.0  # multiplicative size scale (the Pareto x_m)
     weight: float = 1.0  # class weight for weighted policies
     burst: float = 4.0  # MAP on/off rate ratio (multiclass_bursty only)
+    min_chips: int | None = None  # smallest slice a job fits on (whole chips)
+    max_chips: int | None = None  # widest slice a job can use (whole chips)
 
 
 def as_specs(classes) -> tuple[ClassSpec, ...]:
@@ -98,6 +100,25 @@ def as_specs(classes) -> tuple[ClassSpec, ...]:
     if not out:
         raise ValueError("need at least one job class")
     return tuple(out)
+
+
+def has_limits(classes) -> bool:
+    """Whether any class sets a per-job width limit (``min_chips`` or
+    ``max_chips``)."""
+    return classes is not None and any(
+        c.min_chips is not None or c.max_chips is not None for c in as_specs(classes)
+    )
+
+
+def job_limits(classes, class_ids, *, n_chips: int, min_chips: int = 1):
+    """Per-job ``(lo, hi)`` int32 chip limits from each job's class: the
+    class's ``min_chips``/``max_chips``, else the global ``min_chips`` and
+    ``n_chips``."""
+    specs = as_specs(classes)
+    lo = [min_chips if c.min_chips is None else c.min_chips for c in specs]
+    hi = [n_chips if c.max_chips is None else c.max_chips for c in specs]
+    ids = jnp.asarray(class_ids, jnp.int32)
+    return jnp.asarray(lo, jnp.int32)[ids], jnp.asarray(hi, jnp.int32)[ids]
 
 
 def uniform_p(classes) -> float | None:
@@ -315,14 +336,18 @@ def class_rule(
     w: jax.Array | None = None,
     size_factors: jax.Array | None = None,
     p_hat: jax.Array | None = None,
+    lo: jax.Array | None = None,
+    hi: jax.Array | None = None,
 ) -> engine.AllocRule:
     """Build the engine :data:`~repro.core.engine.AllocRule` for a
     class-aware policy: continuous when ``n_chips`` is None, else whole
-    chips (largest-remainder + min-chips floor, optionally slice-snapped).
+    chips (largest-remainder + min-chips floor, optionally slice-snapped;
+    per-job width limits ``lo``/``hi`` take ``engine.finish_alloc``'s
+    capped rounding).
 
     All captured per-job vectors (``w``, ``size_factors``, vector
-    ``p_hat``) must be in the engine's arrival-sorted order — the same
-    contract as ``engine.continuous_rule``.
+    ``p_hat``, ``lo``, ``hi``) must be in the engine's arrival-sorted
+    order — the same contract as ``engine.continuous_rule``.
     """
     n_alloc = float(n_chips) if n_chips is not None else float(n_servers)
 
@@ -332,7 +357,7 @@ def class_rule(
         theta = class_theta(name, x_seen, p_seen, n_servers=n_alloc, w=w)
         return engine.finish_alloc(
             theta, p, n_alloc=n_alloc, n_chips=n_chips, min_chips=min_chips,
-            snap_slices=snap_slices, dtype=dtype,
+            snap_slices=snap_slices, dtype=dtype, lo=lo, hi=hi,
         )
 
     return rule
@@ -358,7 +383,10 @@ def simulate_multiclass(
     samplers); physics use them always, while what the *policy* sees flows
     through the usual estimation-noise channel (``scn.size_factors`` /
     ``scn.p_hat``).  ``n_chips`` switches to whole-chips allocation,
-    ``snap_slices`` additionally restricts jobs to power-of-two slices.
+    ``snap_slices`` additionally restricts jobs to power-of-two slices, and
+    classes with ``min_chips``/``max_chips`` hold each of their jobs within
+    those widths (:func:`job_limits`; whole chips only, truth-driven
+    policies only).
 
     ``estimator_kw`` switches the policy's exponents from the drawn truth
     to *online estimates*: the engine runs the stateful
@@ -382,10 +410,18 @@ def simulate_multiclass(
     arr = jnp.asarray(scn.arrival_times).astype(dtype)
 
     p_shared = uniform_p(specs) if specs is not None else None
+    limited = has_limits(specs)
+    if limited and (n_chips is None or scn.class_ids is None
+                    or estimator_kw is not None):
+        raise ValueError(
+            "per-class width limits need n_chips, a multi-class scenario and "
+            "no estimator"
+        )
     noiseless = scn.size_factors is None and scn.p_hat is None
     if (
         p_shared is not None
         and noiseless
+        and not limited
         and scn.p_drift is None  # drift physics need the generic engine run
         and estimator_kw is None
         and policy.lower() in ("hesrpt", "hesrpt_pc", "hesrpt_blind")
@@ -451,6 +487,11 @@ def simulate_multiclass(
             **kw,
         )
     else:
+        lo = hi = None
+        if limited:
+            lo, hi = job_limits(specs, scn.class_ids, n_chips=n_chips,
+                                min_chips=min_chips)
+            lo, hi = lo[order], hi[order]
         rule = class_rule(
             policy,
             n_servers=float(n_servers),
@@ -461,6 +502,8 @@ def simulate_multiclass(
             w=w,
             size_factors=factors,
             p_hat=p_hat,
+            lo=lo,
+            hi=hi,
         )
     res = engine.run(
         x0, arr, p_job, rule, horizon=horizon, rel_tol=rel_tol,
@@ -531,6 +574,8 @@ __all__ = [
     "as_specs",
     "class_rule",
     "class_theta",
+    "has_limits",
+    "job_limits",
     "multiclass_sweep",
     "per_class_metrics",
     "policy_weights",

@@ -211,7 +211,7 @@ class Sweep(NamedTuple):
         superstep: bool = False,
     ) -> "Sweep":
         from repro.core.arrivals import OnlineSimResult
-        from repro.core.multiclass import as_specs
+        from repro.core.multiclass import as_specs, has_limits
         from repro.core.scenarios import _any_pos
         from repro.core.telemetry import DEFAULT_METRICS, METRICS
 
@@ -288,6 +288,8 @@ class Sweep(NamedTuple):
             )
         if snap_slices and classes is None:
             raise ValueError("snap_slices is only wired for multi-class sweeps")
+        if has_limits(classes):
+            _check_limits(classes, n_chips, min_chips)
         if fused:
             # The fused allocate exists for the quantized heSRPT hot path;
             # continuous heSRPT already dispatches to the (faster) carried-
@@ -390,6 +392,27 @@ class Sweep(NamedTuple):
     def total_jobs(self) -> int:
         """Simulated jobs in the whole grid, per policy."""
         return self.n_seeds * self.jobs_per_seed()
+
+
+def _check_limits(classes, n_chips: int | None, min_chips: int) -> None:
+    """Per-class width limits are slice sizes with ``lo <= hi <= n_chips``,
+    on whole chips only (a class without one takes ``min_chips`` /
+    ``n_chips``)."""
+    from repro.core.engine import DEFAULT_SLICES
+
+    if n_chips is None:
+        raise ValueError("per-class min_chips/max_chips need n_chips (whole chips)")
+    for c in classes:
+        for v in (c.min_chips, c.max_chips):
+            if v is not None and v not in DEFAULT_SLICES:
+                raise ValueError(
+                    f"class width limit {v!r} is not a slice size {DEFAULT_SLICES}")
+        lo = min_chips if c.min_chips is None else c.min_chips
+        hi = n_chips if c.max_chips is None else c.max_chips
+        if not lo <= hi <= n_chips:
+            raise ValueError(
+                f"class width limits need min_chips <= max_chips <= n_chips, "
+                f"got {lo}, {hi}, {n_chips}")
 
 
 # --------------------------------------------------------- per-cell functions

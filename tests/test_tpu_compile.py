@@ -93,3 +93,61 @@ def test_alloc_pallas_refuses_float64(one_chip):
             lambda x: alloc.hesrpt_alloc_fused(x, 0.5, 256, impl="pallas")
         ).lower(x)
 
+
+
+def test_training_pod_executor_compiles_with_cap_and_snap_scopes(one_chip):
+    """The shared-training-pod sweep executor at its benchmark size (16 lanes
+    of 2048 jobs, three classes with width limits, slice snap), in float32:
+    the capped allocate and the snap sit under their own name scopes."""
+    import re
+
+    from repro.core.multiclass import ClassSpec
+
+    classes = (
+        ClassSpec(p=0.3, mix=0.8, size_scale=1.0, min_chips=1, max_chips=8),
+        ClassSpec(p=0.6, mix=0.17, size_scale=8.0, min_chips=8, max_chips=64),
+        ClassSpec(p=0.9, mix=0.03, size_scale=64.0, min_chips=64, max_chips=256),
+    )
+    spec = Sweep.create(
+        ("hesrpt_pc",), (9.99,), scenario="multiclass_poisson", n_jobs=2048,
+        n_seeds=16, n_servers=256.0, n_chips=256, snap_slices=True,
+        classes=classes, metrics=("mean_flowtime", "class_flowtime"),
+    )
+    with jax.enable_x64(False):
+        f = _build_fn(spec, "hesrpt_pc", None, False)
+        text = jax.jit(f).lower(
+            _shape((16, 2), jnp.uint32, one_chip),
+            _shape((1,), jnp.float32, one_chip),
+        ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("engine.cap", "engine.snap"):
+        assert any(re.search(rf"engine\.allocate[/)].*{re.escape(scope)}[/)]", n)
+                   for n in names), scope
+
+
+def test_four_chip_heavy_executor_compiles_sharded_over_seeds(topo, monkeypatch):
+    """The four-chip heavy cell's executor at its benchmark size (32 lanes of
+    1000 jobs at load 0.9, whole chips, float32), sharded over the seeds on
+    the described 2x2 v5e: each chip holds 8 lanes and no collective runs.
+    ``run_sweep`` builds its mesh from ``jax.devices()``, the CPU here, so
+    the test hands it the described chips."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    monkeypatch.setattr(jax, "devices", lambda *a: list(topo.devices))
+    mesh = Mesh(np.asarray(topo.devices), ("seeds",))
+    spec = Sweep.create(
+        ("hesrpt",), (76.8,), n_jobs=1000, n_seeds=32, p=0.5,
+        n_servers=256.0, n_chips=256, min_chips=1,
+    )
+    with jax.enable_x64(False):
+        f = _build_fn(spec, "hesrpt", None, True)
+        text = jax.jit(f).lower(
+            _shape((32, 2), jnp.uint32, NamedSharding(mesh, P("seeds"))),
+            _shape((1,), jnp.float32, NamedSharding(mesh, P())),
+        ).compile().as_text()
+    assert re.search(r"u32\[8,2\]", text)
+    for op in ("all-reduce", "all-gather", "all-to-all", "collective-permute"):
+        assert op + "(" not in text, op
