@@ -61,6 +61,56 @@ class OnlineSimResult(NamedTuple):
     makespan: jax.Array  # scalar, last departure time
 
 
+class PoolSimResult(NamedTuple):
+    """What a sweep reads of one tape played through a slot pool
+    (``engine.run_stream``): the scalar fields of :class:`OnlineSimResult`
+    over every job of the tape, the per-class means where the tape has
+    classes, and whether the pool held."""
+
+    total_flowtime: jax.Array  # scalar
+    mean_flowtime: jax.Array  # scalar
+    mean_slowdown: jax.Array  # scalar
+    makespan: jax.Array  # scalar, last departure time
+    class_flowtime: jax.Array | None  # [K] per-class mean flow time
+    class_slowdown: jax.Array | None  # [K] per-class mean slowdown
+    # The pool deferred an arrival, or a job was left at the horizon: the
+    # numbers are not the tape's; the caller plays it on the full tape.
+    overflow: jax.Array
+
+
+@jax.named_scope("engine.reduce")
+def _finalize_pool(res: engine.StreamResult, n_jobs: int) -> PoolSimResult:
+    """The whole-tape means from the pooled scan's sums (no window)."""
+
+    def means(sums, counts):
+        return jnp.where(counts > 0, sums / jnp.maximum(counts, 1), jnp.nan)
+
+    classes = res.class_count is not None
+    return PoolSimResult(
+        total_flowtime=res.flow_sum,
+        mean_flowtime=res.flow_sum / n_jobs,
+        mean_slowdown=res.slow_sum / n_jobs,
+        makespan=res.t_final,
+        class_flowtime=means(res.class_flow_sum, res.class_count) if classes else None,
+        class_slowdown=means(res.class_slow_sum, res.class_count) if classes else None,
+        overflow=(res.blocked_steps > 0) | (res.n_completed < n_jobs),
+    )
+
+
+def run_pooled(x0, arrival_times, p, rule, *, n_slots: int, n_alone, rel_tol,
+               horizon, class_ids=None, n_classes: int = 0) -> PoolSimResult:
+    """Play a finite tape through ``n_slots`` recycled slots and read it as
+    :func:`_finalize` reads the full tape: equal values wherever the pool
+    held (``overflow`` false; see ``engine.run_stream``), summed in the
+    order jobs finish."""
+    res = engine.run_stream(
+        x0, arrival_times, p, rule, n_slots=n_slots, n_alone=n_alone,
+        horizon=horizon, rel_tol=rel_tol, class_ids=class_ids,
+        n_classes=n_classes,
+    )
+    return _finalize_pool(res, x0.shape[0])
+
+
 @jax.named_scope("engine.reduce")
 def _finalize(x0, arrival_times, times, p, n_servers) -> OnlineSimResult:
     """Per-job flow times / slowdowns from completion times (input order)."""
@@ -260,6 +310,16 @@ def simulate_scenario(
     is then ``(OnlineSimResult, TelemetryResult)``.  The trajectory is
     bit-for-bit the probe-free run either way.
     """
+    return _scenario(scn, p, n_servers, policy, n_chips=n_chips,
+                     min_chips=min_chips, rel_tol=rel_tol, horizon=horizon,
+                     fused=fused, telemetry=telemetry)
+
+
+def _scenario(scn, p, n_servers, policy, *, n_chips, min_chips, rel_tol=1e-9,
+              horizon=None, fused=False, telemetry=None, n_slots: int | None = None):
+    """:func:`simulate_scenario`, or with ``n_slots`` the sweep's slot pool:
+    a whole-chip tape with no drift, probe or fused allocate is then played
+    through :func:`run_pooled` and read as a :class:`PoolSimResult`."""
     x0 = jnp.asarray(scn.x0)
     dtype = jnp.result_type(x0.dtype, jnp.float32)
     x0 = x0.astype(dtype)
@@ -295,6 +355,10 @@ def simulate_scenario(
             size_factors=factors, p_hat=p_hat,
         )
         n_alone = n_servers
+    if (n_slots is not None and n_chips is not None and not fused
+            and telemetry is None and scn.p_drift is None):
+        return run_pooled(x0, arrival_times, p_phys, rule, n_slots=n_slots,
+                          n_alone=n_alone, rel_tol=rel_tol, horizon=horizon)
     res = engine.run(
         x0, arrival_times, p_phys, rule, horizon=horizon, rel_tol=rel_tol,
         p_drift=scn.p_drift, fused=fused, telemetry=telemetry,

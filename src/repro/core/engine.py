@@ -60,7 +60,7 @@ import jax.numpy as jnp
 
 from repro.core.flowtime import speedup
 from repro.core.policies import Policy, equi, hesrpt, knee, srpt
-from repro.core.ranking import in_stable_prefix
+from repro.core.ranking import in_stable_prefix, stable_order
 
 # (x_active, p) -> (alloc, rate); ``alloc`` is theta for continuous rules
 # and integer chips for quantized rules, ``rate`` the per-job service rate.
@@ -192,6 +192,7 @@ def finish_alloc(
     dtype,
     lo: jax.Array | None = None,
     hi: jax.Array | None = None,
+    tie: jax.Array | None = None,
 ):
     """The ONE ``theta -> (alloc, rate)`` tail every allocation rule shares.
 
@@ -202,7 +203,10 @@ def finish_alloc(
     (:func:`snap_to_slices_jax`), rate ``s(chips)``.  Per-job width limits
     ``lo``/``hi`` (slice sizes, in the engine's arrival-sorted order) take
     the capped rounding and bound the snap's upgrades; without them the
-    program is the limit-free one.  Centralized so the
+    program is the limit-free one.  ``tie`` holds each row's job id where
+    rows are slots of a pool, not the tape in arrival order: the rounding's
+    and the snap's ties then break by job id, as they break by index on
+    the tape.  Centralized so the
     stateless rules here, :func:`knee_rule`, the class-aware rules
     (``core/multiclass.py``) and the estimating rules
     (``core/estimation.py``) cannot desynchronize on quantization order or
@@ -211,11 +215,31 @@ def finish_alloc(
     theta = theta.astype(dtype)
     if n_chips is None:
         return theta, speedup(theta * n_alloc, p)
-    chips = quantize_allocation_jax(theta, n_chips, min_chips=min_chips, lo=lo, hi=hi)
+    chips = quantize_allocation_jax(theta, n_chips, min_chips=min_chips, lo=lo,
+                                    hi=hi, tie=tie)
     if snap_slices:
         with jax.named_scope("engine.snap"):
-            chips = snap_to_slices_jax(chips, n_chips, slices=slices, hi=hi)
+            chips = snap_to_slices_jax(chips, n_chips, slices=slices, hi=hi,
+                                       tie=tie)
     return chips, speedup(chips.astype(dtype), p)
+
+
+def slotted_rule(make, job: dict):
+    """The rule ``make(job, None)``, which can also be made over a pool.
+
+    ``make(job, tie)`` builds a rule from ``job``, a dict of what it reads
+    per job (``None`` where absent).  The vectors of ``job`` are in the
+    tape's arrival order; the rule returned carries them as ``slot_job``
+    and carries ``on_slots(slot_job, tie)``, which makes the same rule
+    over the slot-ordered copies a pool gathers on admission, with the
+    slots' job ids as ``tie``.  :func:`run_stream` plays such a rule
+    through slots; :func:`run` plays it as it is.
+    """
+    rule = make(job, None)
+    per_job = {k: v for k, v in job.items() if v is not None and jnp.ndim(v) >= 1}
+    rule.slot_job = per_job
+    rule.on_slots = lambda slot_job, tie: make({**job, **slot_job}, tie)
+    return rule
 
 
 def continuous_rule(
@@ -295,18 +319,26 @@ def quantized_rule(
     For the heSRPT policy the returned rule carries a ``fused_variant``
     attribute: the ``kernels/alloc.py`` fused rank -> theta -> chips pass
     (3 sorts per event instead of 4 on CPU, 0 on TPU), chip-exact vs this
-    rule, selected by :func:`run`'s ``fused=True``.
+    rule, selected by :func:`run`'s ``fused=True``.  The rule can also be
+    played through a slot pool (``on_slots``, see :func:`slotted_rule`): its
+    noise factors then ride in the slots.
     """
 
-    def rule(x_act, p):
-        x_seen = x_act if size_factors is None else x_act * size_factors
-        p_seen = p if p_hat is None else p_hat
-        return finish_alloc(
-            policy(x_seen, p_seen), p, n_alloc=n_chips, n_chips=n_chips,
-            min_chips=min_chips, snap_slices=snap_slices, slices=slices,
-            dtype=dtype,
-        )
+    def make(job, tie):
+        factors, p_hat_ = job["size_factors"], job["p_hat"]
 
+        def rule(x_act, p):
+            x_seen = x_act if factors is None else x_act * factors
+            p_seen = p if p_hat_ is None else p_hat_
+            return finish_alloc(
+                policy(x_seen, p_seen), p, n_alloc=n_chips, n_chips=n_chips,
+                min_chips=min_chips, snap_slices=snap_slices, slices=slices,
+                dtype=dtype, tie=tie,
+            )
+
+        return rule
+
+    rule = slotted_rule(make, {"size_factors": size_factors, "p_hat": p_hat})
     if policy is hesrpt:
         from repro.kernels.alloc import hesrpt_alloc_fused
 
@@ -745,20 +777,27 @@ class StreamSource(NamedTuple):
     split is what lets :func:`run_stream` defer an arrival for any number
     of events while the slot pool is full and still admit it later — the
     recorded arrival time stays the stream's true one, so blocked wait
-    counts toward flow time.
+    counts toward flow time.  ``attrs(state)``, where given, reads the next
+    arrival's other per-job attributes (a pytree of scalars), which the
+    scan gathers into the slot that admits it.
     """
 
     init: Callable[[], Any]
     peek: Callable[[Any], tuple[jax.Array, jax.Array]]
     advance: Callable[[Any], Any]
+    attrs: Callable[[Any], Any] | None = None
 
 
-def tape_source(x0_sorted: jax.Array, arrivals_sorted: jax.Array) -> StreamSource:
+def tape_source(
+    x0_sorted: jax.Array, arrivals_sorted: jax.Array, attrs: Any = None,
+) -> StreamSource:
     """A finite, arrival-sorted ``(sizes, times)`` tape as a StreamSource.
 
     State is the next tape index; :func:`run_stream`'s admission counter
     then equals the tape position, which is what lets it scatter
-    completion times back to jobs (``record_times=True``).
+    completion times back to jobs (``record_times=True``).  ``attrs`` is a
+    pytree of per-job ``[T]`` vectors in the same order (exponents, width
+    limits, class ids, ...), read one job at a time by ``attrs(state)``.
     """
     x0_sorted = jnp.asarray(x0_sorted)
     arrivals_sorted = jnp.asarray(arrivals_sorted)
@@ -775,7 +814,12 @@ def tape_source(x0_sorted: jax.Array, arrivals_sorted: jax.Array) -> StreamSourc
     def advance(i):
         return i + 1
 
-    return StreamSource(init=init, peek=peek, advance=advance)
+    def job(i):
+        j = jnp.minimum(i, T - 1)
+        return jax.tree.map(lambda a: a[j], attrs)
+
+    return StreamSource(init=init, peek=peek, advance=advance,
+                        attrs=None if attrs is None else job)
 
 
 def poisson_source(key: jax.Array, rate, *, size_alpha: float = 1.5, dtype) -> StreamSource:
@@ -840,6 +884,9 @@ class StreamResult(NamedTuple):
     x_final: jax.Array  # [n_slots] remaining sizes (0 = free slot)
     completion_times: jax.Array | None  # [n_jobs] input order (record_times)
     telemetry: Any  # TelemetryResult when a probe was attached
+    class_flow_sum: jax.Array | None = None  # [n_classes] windowed, by class
+    class_slow_sum: jax.Array | None = None  # [n_classes] windowed, by class
+    class_count: jax.Array | None = None  # [n_classes] windowed completions
 
 
 def _window_bounds(window, dtype):
@@ -866,28 +913,43 @@ def _finalize_stream(acc, t_fin, x_fin, comp, tel, dtype) -> StreamResult:
         x_final=x_fin,
         completion_times=comp,
         telemetry=tel,
+        class_flow_sum=acc.get("c_flow"),
+        class_slow_sum=acc.get("c_slow"),
+        class_count=acc.get("c_count"),
     )
 
 
 def _stream_scan(
     source: StreamSource, p, srule: StatefulRule, *, n_slots: int,
     n_events: int, w_lo, w_hi, alone_rate, tol, t0, dtype, n_times: int,
-    telemetry,
+    telemetry, slot_rule=None, n_classes: int = 0,
 ):
     """The bounded-slot event scan shared by the tape and source runners.
 
     Carries only ``[n_slots]`` per-job state (remaining size, original
-    size, arrival time, job id) plus O(1) scalars, so memory and per-event
-    cost are flat in the number of jobs ever streamed.  Slot lifecycle:
-    a slot is *free* iff its remaining size is 0; an admitted arrival
-    claims the free slot with the smallest cyclic offset after a rotating
-    ring pointer and a completion simply zeroes its slot.  With
-    ``n_slots >= n_jobs`` the pointer never wraps, slot ``i`` is the
-    ``i``-th arrival, and every per-step quantity equals :func:`run`'s —
-    the bit-for-bit reduction the tests pin.  When the pool is full the
-    next arrival is *deferred* (the arrival leg of the event race drops
-    out) and admitted — at its true arrival time, so the wait counts
-    toward flow — on a later event once a departure frees a slot.
+    size, arrival time, job id, and the source's per-job attributes) plus
+    O(1) scalars, so memory and per-event cost are flat in the number of
+    jobs ever streamed.  Slot lifecycle: a slot is *free* iff its
+    remaining size is 0; an admitted arrival claims the free slot with the
+    smallest cyclic offset after a rotating ring pointer and a completion
+    simply zeroes its slot.  When the pool is full the next arrival is
+    *deferred* (the arrival leg of the event race drops out) and admitted
+    — at its true arrival time, so the wait counts toward flow — on a
+    later event once a departure frees a slot.
+
+    Per-job attributes ride in the slots: what ``source.attrs`` reads for
+    the next arrival (a pytree of scalars) is gathered into the slot that
+    admits it, beside its size.  Of those the scan itself reads ``"p"``
+    (the job's exponent, shown to the rule and driving its rate in place
+    of the scalar ``p``), ``"alone"`` (its rate alone, for the slowdown, in
+    place of ``alone_rate``) and ``"cls"`` (its class, for the per-class
+    sums when ``n_classes``); ``"rule"`` goes to ``slot_rule(rule_attrs,
+    sid)``, which makes the step's rule over them (``slotted_rule``), with
+    the slots' job ids to break its ties.  Ties in the departure race also
+    break by job id.  So wherever a row's index stood for a job's place on
+    the tape, the job id stands for it here, and as long as the pool never
+    defers an arrival (``blocked == 0``) every per-job quantity equals
+    :func:`run`'s — the identity the tests pin.
     """
     S = int(n_slots)
     idx = jnp.arange(S)
@@ -897,107 +959,145 @@ def _stream_scan(
         "w_arrived": zi, "blocked": zi, "occ_max": zi,
         "w_flow": jnp.zeros((), dtype), "w_slow": jnp.zeros((), dtype),
     }
+    if n_classes:
+        acc0.update(c_flow=jnp.zeros(n_classes, dtype),
+                    c_slow=jnp.zeros(n_classes, dtype),
+                    c_count=jnp.zeros(n_classes, jnp.int32))
+    classes = jnp.arange(n_classes, dtype=jnp.int32)
+    no_id = jnp.iinfo(jnp.int32).max
 
     def body(carry, _):
         if telemetry is None:
             slots, t, ptr, src, st, acc, times = carry
         else:
             slots, t, ptr, src, st, acc, times, tel = carry
-        x, sx0, sarr, sid = slots
-        active = x > 0  # free slots hold exactly 0, like completed jobs
-        x_act = jnp.where(active, x, 0.0)
-        alloc, rate = srule.allocate(st, x_act, p)
-        tt = jnp.where(active & (rate > 0), x / rate, jnp.inf)
-        dt_dep = jnp.min(tt)
-        t_next, x_next = source.peek(src)
-        dt_arr = jnp.maximum(t_next - t, 0.0)
-        free = ~active
-        has_free = jnp.any(free)
-        # A full pool defers the arrival: it drops out of the event race
-        # until a departure frees a slot.
-        eff_dt_arr = jnp.where(has_free, dt_arr, jnp.inf)
-        dt = jnp.minimum(dt_dep, eff_dt_arr)
-        any_event = jnp.isfinite(dt)
-        dt = jnp.where(any_event, dt, 0.0)
-        admit = any_event & has_free & (dt_arr <= dt_dep)
-        take_dep = any_event & (dt_dep <= eff_dt_arr)
-        blocked_now = jnp.isfinite(dt_dep) & ~has_free & (dt_arr < dt_dep)
-        # On-time admissions pin t to the exact arrival time (as in `run`);
-        # a deferred arrival is admitted at the later clock t.
-        t_new = jnp.where(admit, jnp.maximum(t_next, t), t + dt)
-        x_new = jnp.where(active, x - dt * rate, x)
-        departing = (idx == jnp.argmin(tt)) & active & take_dep
-        x_new = jnp.where(departing | (active & (x_new <= tol)), 0.0, x_new)
-        newly_done = active & (x_new == 0.0)
-        # Windowed flow/slowdown, vectorized: the tol clamp can finish
-        # several stragglers in one step.  sx0 init 1.0 keeps idle slots'
-        # (masked-out) slowdown read free of 0/0.
-        flow = t_new - sarr
-        slow = flow * alone_rate / sx0
-        done_w = newly_done & (sarr >= w_lo) & (sarr < w_hi)
-        if times is not None:
-            tix = jnp.where(newly_done, sid, n_times)
-            times = times.at[tix].set(t_new, mode="drop")
-        # Claim: the free slot at the smallest cyclic offset after the
-        # ring pointer (epoch-start free mask — the departing slot is
-        # claimable from the *next* event, matching the admit gate above).
-        offs = (idx - ptr) % S
-        cand = jnp.argmin(jnp.where(free, offs, S)).astype(jnp.int32)
-        claimed = admit & (idx == cand)
-        arr_id = acc["n_admitted"]
-        x_new = jnp.where(claimed, x_next, x_new)
-        acc_new = {
-            "n_admitted": arr_id + admit,
-            "n_completed": acc["n_completed"]
-            + jnp.sum(newly_done, dtype=jnp.int32),
-            "w_count": acc["w_count"] + jnp.sum(done_w, dtype=jnp.int32),
-            "w_arrived": acc["w_arrived"]
-            + (admit & (t_next >= w_lo) & (t_next < w_hi)),
-            "blocked": acc["blocked"] + blocked_now,
-            "occ_max": jnp.maximum(
-                acc["occ_max"], jnp.sum(active, dtype=jnp.int32)
-            ),
-            "w_flow": acc["w_flow"] + jnp.sum(jnp.where(done_w, flow, 0.0)),
-            "w_slow": acc["w_slow"] + jnp.sum(jnp.where(done_w, slow, 0.0)),
-        }
-        slots_new = (
-            x_new,
-            jnp.where(claimed, x_next, sx0),
-            jnp.where(claimed, t_next, sarr),
-            jnp.where(claimed, arr_id, sid),
-        )
-        ptr_new = jnp.where(admit, (cand + 1) % S, ptr)
-        src_adv = source.advance(src)
-        src_new = jax.tree.map(
-            lambda a, b: jnp.where(admit, a, b), src_adv, src
-        )
-        st_new = srule.observe(
-            st, Observation(alloc=alloc, rate=rate, dt=dt, active=active)
-        )
-        if telemetry is None:
-            carry = (slots_new, t_new, ptr_new, src_new, st_new, acc_new, times)
-            return carry, None
-        tel_new, tel_out = telemetry.step(
-            tel,
-            ProbeEvent(
-                t=t, dt=dt, alloc=alloc, rate=rate, active=active, x=x,
-                p=p, rule_state=st,
-            ),
-        )
-        carry = (
-            slots_new, t_new, ptr_new, src_new, st_new, acc_new, times, tel_new
-        )
-        return carry, tel_out
+        x, sx0, sarr, sid, sjob = slots
+        p_now = sjob.get("p", p)
+        rule = srule if slot_rule is None else as_stateful(
+            slot_rule(sjob["rule"], sid))
+        with jax.named_scope("engine.advance"):
+            active = x > 0  # free slots hold exactly 0, like completed jobs
+            x_act = jnp.where(active, x, 0.0)
+        with jax.named_scope("engine.allocate"):
+            alloc, rate = rule.allocate(st, x_act, p_now)
+        with jax.named_scope("engine.advance"):
+            tt = jnp.where(active & (rate > 0), x / rate, jnp.inf)
+            dt_dep = jnp.min(tt)
+            t_next, x_next = source.peek(src)
+            dt_arr = jnp.maximum(t_next - t, 0.0)
+            free = ~active
+            has_free = jnp.any(free)
+            # A full pool defers the arrival: it drops out of the event race
+            # until a departure frees a slot.
+            eff_dt_arr = jnp.where(has_free, dt_arr, jnp.inf)
+            dt = jnp.minimum(dt_dep, eff_dt_arr)
+            any_event = jnp.isfinite(dt)
+            dt = jnp.where(any_event, dt, 0.0)
+            admit = any_event & has_free & (dt_arr <= dt_dep)
+            take_dep = any_event & (dt_dep <= eff_dt_arr)
+            blocked_now = jnp.isfinite(dt_dep) & ~has_free & (dt_arr <= dt_dep)
+            # On-time admissions pin t to the exact arrival time (as in `run`);
+            # a deferred arrival is admitted at the later clock t.
+            t_new = jnp.where(admit, jnp.maximum(t_next, t), t + dt)
+            x_new = jnp.where(active, x - dt * rate, x)
+            # The departer of a tie is the earliest job, as `run`'s argmin
+            # takes the lowest tape index.
+            first = jnp.min(jnp.where(tt == dt_dep, sid, no_id))
+            departing = (sid == first) & active & take_dep
+            x_new = jnp.where(departing | (active & (x_new <= tol)), 0.0, x_new)
+            newly_done = active & (x_new == 0.0)
+            # Windowed flow/slowdown, vectorized: the tol clamp can finish
+            # several stragglers in one step.  sx0 init 1.0 keeps idle slots'
+            # (masked-out) slowdown read free of 0/0.
+            flow = t_new - sarr
+            slow = flow * sjob.get("alone", alone_rate) / sx0
+            done_w = newly_done & (sarr >= w_lo) & (sarr < w_hi)
+            if times is not None:
+                tix = jnp.where(newly_done, sid, n_times)
+                times = times.at[tix].set(t_new, mode="drop")
+            # Claim: the free slot at the smallest cyclic offset after the
+            # ring pointer (epoch-start free mask — the departing slot is
+            # claimable from the *next* event, matching the admit gate above).
+            offs = (idx - ptr) % S
+            cand = jnp.argmin(jnp.where(free, offs, S)).astype(jnp.int32)
+            claimed = admit & (idx == cand)
+            arr_id = acc["n_admitted"]
+            x_new = jnp.where(claimed, x_next, x_new)
+            acc_new = {
+                "n_admitted": arr_id + admit,
+                "n_completed": acc["n_completed"]
+                + jnp.sum(newly_done, dtype=jnp.int32),
+                "w_count": acc["w_count"] + jnp.sum(done_w, dtype=jnp.int32),
+                "w_arrived": acc["w_arrived"]
+                + (admit & (t_next >= w_lo) & (t_next < w_hi)),
+                "blocked": acc["blocked"] + blocked_now,
+                "occ_max": jnp.maximum(
+                    acc["occ_max"], jnp.sum(active, dtype=jnp.int32)
+                ),
+                "w_flow": acc["w_flow"] + jnp.sum(jnp.where(done_w, flow, 0.0)),
+                "w_slow": acc["w_slow"] + jnp.sum(jnp.where(done_w, slow, 0.0)),
+            }
+            if n_classes:
+                mine = done_w[:, None] & (sjob["cls"][:, None] == classes)
+                acc_new.update(
+                    c_flow=acc["c_flow"]
+                    + jnp.sum(jnp.where(mine, flow[:, None], 0.0), axis=0),
+                    c_slow=acc["c_slow"]
+                    + jnp.sum(jnp.where(mine, slow[:, None], 0.0), axis=0),
+                    c_count=acc["c_count"] + jnp.sum(mine, axis=0, dtype=jnp.int32),
+                )
+            job_next = source.attrs(src) if source.attrs is not None else {}
+            slots_new = (
+                x_new,
+                jnp.where(claimed, x_next, sx0),
+                jnp.where(claimed, t_next, sarr),
+                jnp.where(claimed, arr_id, sid),
+                jax.tree.map(lambda a, b: jnp.where(claimed, a, b), job_next, sjob),
+            )
+            ptr_new = jnp.where(admit, (cand + 1) % S, ptr)
+            src_adv = source.advance(src)
+            src_new = jax.tree.map(
+                lambda a, b: jnp.where(admit, a, b), src_adv, src
+            )
+            st_new = rule.observe(
+                st, Observation(alloc=alloc, rate=rate, dt=dt, active=active)
+            )
+            if telemetry is None:
+                carry = (slots_new, t_new, ptr_new, src_new, st_new, acc_new, times)
+                return carry, None
+            tel_new, tel_out = telemetry.step(
+                tel,
+                ProbeEvent(
+                    t=t, dt=dt, alloc=alloc, rate=rate, active=active, x=x,
+                    p=p_now, rule_state=st,
+                ),
+            )
+            carry = (
+                slots_new, t_new, ptr_new, src_new, st_new, acc_new, times, tel_new
+            )
+            return carry, tel_out
 
+    src0 = source.init()
+    job0 = source.attrs(src0) if source.attrs is not None else {}
     slots0 = (
         jnp.zeros(S, dtype),  # remaining size: free slots hold 0
         jnp.ones(S, dtype),  # original size (1.0: see slowdown note above)
         jnp.zeros(S, dtype),  # arrival time
         jnp.full(S, n_times, jnp.int32),  # job id (sentinel = never used)
+        # other attributes: free slots' are never read
+        jax.tree.map(lambda a: jnp.zeros(S, jnp.asarray(a).dtype), job0),
     )
     times0 = jnp.full(n_times, jnp.inf, dtype) if n_times else None
-    init = (slots0, jnp.asarray(t0, dtype), zi, source.init(), srule.init(),
+    init = (slots0, jnp.asarray(t0, dtype), zi, src0, srule.init(),
             acc0, times0)
+    # Under vmap a carry that starts as a constant turns per-lane after a
+    # trip, and the scan traces its body once more for each link of that
+    # chain.  Adding a per-lane zero (the first arrival is never below
+    # -inf) starts every numeric carry per-lane: one trace of the body.
+    lane = source.peek(src0)[0] < -jnp.inf
+    init = jax.tree.map(
+        lambda a: a + lane.astype(a.dtype) if jnp.issubdtype(a.dtype, jnp.number)
+        else a, init)
     if telemetry is not None:
         init = (*init, telemetry.init())
     carry_fin, tel_ys = jax.lax.scan(body, init, None, length=n_events)
@@ -1023,6 +1123,8 @@ def run_stream(
     record_times: bool = False,
     fused: bool = False,
     telemetry: Any = None,
+    class_ids: jax.Array | None = None,
+    n_classes: int = 0,
 ) -> StreamResult:
     """:func:`run` over a fixed pool of ``n_slots`` recycled job slots.
 
@@ -1036,26 +1138,33 @@ def run_stream(
     (deferred-admission) semantics, and :func:`run_stream_source` for the
     tape-free unbounded variant.
 
-    Reduction: with ``n_slots >= n_jobs`` the trajectory is value-
-    identical to :func:`run` on the same tape (tested bit-for-bit), with
-    two measure-zero caveats — exactly tied arrival times are admitted
-    one per event here (extra zero-length epochs; `run` batch-admits
-    them), and a departure epoch whose float rounding overshoots the next
-    arrival time admits that arrival one epoch later.
+    Per-job attributes ride in the slots.  ``p`` may be a per-job vector
+    in input order, as in :func:`run`; so may ``class_ids`` (int, with
+    ``n_classes`` classes), which adds the per-class windowed sums
+    ``class_flow_sum``/``class_slow_sum``/``class_count``.  A rule built
+    by :func:`quantized_rule` or ``multiclass.class_rule`` carries its
+    per-job vectors (noise factors, weights, width limits; arrival-sorted,
+    the same contract as :func:`run`) in ``slot_job`` and is made anew
+    over their slot copies at every event, ties broken by job id; any
+    other rule must read nothing per job but what it is called with.
+
+    Reduction: as long as no arrival is deferred (``blocked_steps == 0``,
+    which holds whenever ``n_slots`` is at least the most jobs in flight)
+    the trajectory is value-identical to :func:`run` on the same tape
+    (tested bit-for-bit), with two measure-zero caveats — exactly tied
+    arrival times are admitted one per event here (extra zero-length
+    epochs; `run` batch-admits them), and a departure epoch whose float
+    rounding overshoots the next arrival time admits that arrival one
+    epoch later — and with sums over the slots, where a rule takes any, in
+    another order than over the tape.
 
     ``window=(lo, hi)`` selects the stationary measurement window (see
-    :class:`StreamResult`); ``record_times=True`` additionally scatters
-    per-job completion times (input order) through an ``[n_jobs]`` carry
-    — parity/debug tooling, not the O(n_slots) production path.  ``p``
-    must be a scalar: per-job exponents would have to ride in the slots
-    (future work), and ``p_drift``'s global regime clock belongs to the
+    :class:`StreamResult`; None sums over every job); ``record_times=True``
+    additionally scatters per-job completion times (input order) through
+    an ``[n_jobs]`` carry — parity/debug tooling, not the O(n_slots)
+    production path.  ``p_drift``'s global regime clock belongs to the
     finite-tape engine.
     """
-    if jnp.ndim(p) != 0:
-        raise ValueError(
-            "run_stream needs a scalar p — per-job exponents do not ride "
-            "in slots yet; multi-class streams take the finite-tape run()"
-        )
     rule = _resolve_fused(rule, fused)
     x0 = jnp.asarray(x0)
     T = x0.shape[0]
@@ -1065,13 +1174,27 @@ def run_stream(
     arrival_times = jnp.asarray(arrival_times).astype(dtype)
     tol = rel_tol * jnp.max(x0)
     order = jnp.argsort(arrival_times)
-    source = tape_source(x0[order], arrival_times[order])
+    n_alone = jnp.asarray(n_alone, dtype)
+    job = {}
+    alone_rate = None
+    if jnp.ndim(p) >= 1:  # per-job exponents travel with their jobs
+        job["p"] = jnp.asarray(p)[order]
+        job["alone"] = speedup(n_alone, job["p"])
+    else:
+        alone_rate = speedup(n_alone, p)
+    if n_classes:
+        job["cls"] = jnp.asarray(class_ids, jnp.int32)[order]
+    slot_rule = getattr(rule, "on_slots", None)
+    if slot_rule is not None:
+        job["rule"] = rule.slot_job
+    source = tape_source(x0[order], arrival_times[order], job or None)
     w_lo, w_hi = _window_bounds(window, dtype)
     x_fin, t_fin, acc, times, tel = _stream_scan(
         source, p, as_stateful(rule), n_slots=n_slots, n_events=E,
-        w_lo=w_lo, w_hi=w_hi, alone_rate=speedup(jnp.asarray(n_alone, dtype), p),
+        w_lo=w_lo, w_hi=w_hi, alone_rate=alone_rate,
         tol=tol, t0=jnp.asarray(t0, dtype), dtype=dtype,
         n_times=T if record_times else 0, telemetry=telemetry,
+        slot_rule=slot_rule, n_classes=n_classes,
     )
     comp = None
     if record_times:
@@ -1107,8 +1230,8 @@ def run_stream_source(
     """
     if jnp.ndim(p) != 0:
         raise ValueError(
-            "run_stream_source needs a scalar p — per-job exponents do "
-            "not ride in slots yet"
+            "run_stream_source needs a scalar p — a source brings per-job "
+            "exponents with its arrivals (StreamSource.attrs), not as a vector"
         )
     rule = _resolve_fused(rule, fused)
     w_lo, w_hi = _window_bounds(window, dtype)
@@ -1273,6 +1396,7 @@ def quantize_allocation_jax(
     min_chips: int = 1,
     lo: jax.Array | None = None,
     hi: jax.Array | None = None,
+    tie: jax.Array | None = None,
 ) -> jax.Array:
     """Vectorized-jnp port of ``sched.quantize.quantize_allocation``.
 
@@ -1310,7 +1434,9 @@ def quantize_allocation_jax(
     Per-job width limits ``lo``/``hi`` (int arrays, either may be None:
     ``lo`` then defaults to ``min_chips``, ``hi`` to ``n_chips``) take
     :func:`_quantize_capped` instead; with both None this is the
-    limit-free program, decided here in Python.
+    limit-free program, decided here in Python.  ``tie`` (int, or None)
+    breaks the sorts' ties before the index does (``ranking.stable_order``):
+    a slot pool passes its slots' job ids.
 
     ``n_chips``/``min_chips`` are static Python ints.  Returns int32 chips.
     """
@@ -1320,7 +1446,7 @@ def quantize_allocation_jax(
         return jnp.zeros(M, jnp.int32)
     if lo is not None or hi is not None:
         with jax.named_scope("engine.cap"):
-            return _quantize_capped(theta, n_chips, min_chips, lo, hi)
+            return _quantize_capped(theta, n_chips, min_chips, lo, hi, tie)
     cap = n_chips // min_chips  # most jobs the floor allows us to serve
 
     active0 = theta > 0
@@ -1328,7 +1454,7 @@ def quantize_allocation_jax(
     # Oversubscribed: serve the largest-theta jobs (stable on ties), queue
     # the rest with 0, renormalize — the oracle's single recursion, unrolled.
     key0 = jnp.where(active0, -theta, jnp.inf)
-    servable = active0 & in_stable_prefix(key0, jnp.argsort(key0), cap)
+    servable = active0 & in_stable_prefix(key0, stable_order(key0, tie), cap, tie)
     over = n_active * min_chips > n_chips
     sub = jnp.where(servable, theta, 0.0)
     tot = jnp.sum(sub)
@@ -1339,10 +1465,10 @@ def quantize_allocation_jax(
     fl = jnp.floor(raw)
     frac = raw - fl
     base = jnp.where(active, jnp.maximum(fl, min_chips), 0.0).astype(jnp.int32)
-    return _trim_and_fill(base, frac, active, min_chips, n_chips)
+    return _trim_and_fill(base, frac, active, min_chips, n_chips, tie=tie)
 
 
-def _trim_and_fill(base, frac, active, floor, n_chips: int, room=None):
+def _trim_and_fill(base, frac, active, floor, n_chips: int, room=None, tie=None):
     """The largest-remainder tail shared by both quantizers: trim an
     overflowing floor from the largest holdings (never below ``floor``, a
     Python int or a per-job array), else hand the leftover chips to the
@@ -1375,17 +1501,17 @@ def _trim_and_fill(base, frac, active, floor, n_chips: int, room=None):
     key = jnp.where(
         trim, jnp.where(elig, frac, jnp.inf), jnp.where(room, -frac, jnp.inf)
     )
-    order = jnp.argsort(key)
-    extra = (elig & in_stable_prefix(key, order, extra_needed)).astype(jnp.int32)
+    order = stable_order(key, tie)
+    extra = (elig & in_stable_prefix(key, order, extra_needed, tie)).astype(jnp.int32)
     base = base - full - extra
 
     # Leftover chips (only when no trim happened): largest fracs first.
     remainder = n_chips - jnp.sum(base)
-    base = base + (room & in_stable_prefix(key, order, remainder)).astype(jnp.int32)
+    base = base + (room & in_stable_prefix(key, order, remainder, tie)).astype(jnp.int32)
     return base
 
 
-def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi):
+def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi, tie=None):
     """Whole chips within per-job width limits ``lo <= chips <= hi``.
 
     1. **Admission**: the longest prefix of the active jobs by descending
@@ -1409,7 +1535,8 @@ def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi):
     Three sorts (admission, cap order, fractional parts), each carrying
     what it orders as sort operands: no permutation gather.  The chips sum
     to at most ``n_chips`` (less only when the served jobs' ``hi`` sum to
-    less).  Returns int32 chips.
+    less).  With ``tie``, each sort breaks ties by it before the index.
+    Returns int32 chips.
     """
     M = theta.shape[0]
     lo = jnp.broadcast_to(
@@ -1424,14 +1551,17 @@ def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi):
         # order, and the payload in that order without a gather (a
         # permutation gather runs one element at a time on a TPU v5e, as a
         # scatter does).
-        return jax.lax.sort((key, idx, *payload), num_keys=1, is_stable=True)
+        if tie is None:
+            return jax.lax.sort((key, idx, *payload), num_keys=1, is_stable=True)
+        out = jax.lax.sort((key, tie, idx, *payload), num_keys=2, is_stable=True)
+        return (out[0], *out[2:])
 
     active0 = theta > 0
     key0 = jnp.where(active0, -theta, jnp.inf)
     _, order0, lo_s = sort_by(key0, jnp.where(active0, lo, 0))
     # At most n_chips jobs can be served (every lo >= 1): a short prefix.
     need = jnp.cumsum(lo_s[: min(M, n_chips)])
-    served = active0 & in_stable_prefix(key0, order0, jnp.sum(need <= n_chips))
+    served = active0 & in_stable_prefix(key0, order0, jnp.sum(need <= n_chips), tie)
     sub = jnp.where(served, theta, 0.0)
     tot = jnp.sum(sub)
     over = jnp.sum(jnp.where(active0, lo, 0)) > n_chips
@@ -1452,7 +1582,7 @@ def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi):
     tn_from = jnp.cumsum(tn_s[:c][::-1])[::-1] + jnp.sum(tn_s[c:])
     short = jnp.isfinite(k_s[:c]) & (hi_before + k_s[:c] * tn_from < n_chips)
     n_capped = jnp.min(jnp.where(short, c, idx[:c]))  # leading run
-    capped = active & in_stable_prefix(k, order1, n_capped)
+    capped = active & in_stable_prefix(k, order1, n_capped, tie)
     free = n_chips - jnp.sum(jnp.where(capped, hi_f, 0.0))
     rest = jnp.sum(jnp.where(active & ~capped, t_n, 0.0))
     scale = jnp.where(rest > 0, free / jnp.where(rest > 0, rest, 1.0), 0.0)
@@ -1461,7 +1591,8 @@ def _quantize_capped(theta, n_chips: int, min_chips: int, lo, hi):
     fl = jnp.floor(raw)
     frac = raw - fl
     base = jnp.where(active, jnp.clip(fl, lo, hi), 0.0).astype(jnp.int32)
-    return _trim_and_fill(base, frac, active, lo, n_chips, room=active & (base < hi))
+    return _trim_and_fill(base, frac, active, lo, n_chips, room=active & (base < hi),
+                          tie=tie)
 
 
 def snap_to_slices_jax(
@@ -1470,6 +1601,7 @@ def snap_to_slices_jax(
     *,
     slices: tuple[int, ...] = DEFAULT_SLICES,
     hi: jax.Array | None = None,
+    tie: jax.Array | None = None,
 ) -> jax.Array:
     """Vectorized-jnp port of ``sched.quantize.snap_to_slices``.
 
@@ -1482,7 +1614,9 @@ def snap_to_slices_jax(
     leftover pool strictly shrinks every round, so the ``while_loop`` is
     bounded by ``n_chips`` iterations.  A per-job ceiling ``hi`` makes an
     upgrade eligible only while the next slice is at most ``hi``; snapping
-    down keeps a job at or above a floor that is itself a slice.
+    down keeps a job at or above a floor that is itself a slice.  With
+    ``tie`` (a slot pool's job ids) ties break toward the highest ``tie``
+    instead of the highest index.
 
     ``n_chips``/``slices`` are static; returns int32 chips.  Exact
     agreement with the NumPy oracle is property-tested in
@@ -1515,10 +1649,17 @@ def snap_to_slices_jax(
         )
         if hi is not None:
             elig = elig & (nxt <= hi)
-        # Max lost, ties to the highest index — the oracle's `>=` scan.
-        key = jnp.where(elig, lost * M + idx, -1)
-        pick = idx == jnp.argmax(key)
-        return pick, nxt, jnp.sum(jnp.where(pick, step, 0)), jnp.max(key) >= 0
+        if tie is None:
+            # Max lost, ties to the highest index — the oracle's `>=` scan.
+            key = jnp.where(elig, lost * M + idx, -1)
+            pick = idx == jnp.argmax(key)
+            return pick, nxt, jnp.sum(jnp.where(pick, step, 0)), jnp.max(key) >= 0
+        # Max lost, ties to the highest job id (unique among the jobs that
+        # hold chips, the only eligible ones).
+        best = jnp.max(jnp.where(elig, lost, -1))
+        top = elig & (lost == best)
+        pick = top & (tie == jnp.max(jnp.where(top, tie, -1)))
+        return pick, nxt, jnp.sum(jnp.where(pick, step, 0)), best >= 0
 
     # The chosen candidate rides in the carry so each round computes it
     # once (the next candidate is derived at the end of body, not re-done
@@ -1561,6 +1702,7 @@ __all__ = [
     "run_stream",
     "run_stream_ranked",
     "run_stream_source",
+    "slotted_rule",
     "snap_to_slices_jax",
     "tape_source",
 ]

@@ -53,6 +53,7 @@ from repro.core.analysis import per_class_mean
 from repro.core.arrivals import (
     OnlineSimResult,
     _finalize,
+    run_pooled,
     simulate_online,
     simulate_online_quantized,
 )
@@ -347,20 +348,29 @@ def class_rule(
 
     All captured per-job vectors (``w``, ``size_factors``, vector
     ``p_hat``, ``lo``, ``hi``) must be in the engine's arrival-sorted
-    order — the same contract as ``engine.continuous_rule``.
+    order — the same contract as ``engine.continuous_rule``.  The rule can
+    also be played through a slot pool (``engine.run_stream``): those
+    vectors then ride in the slots, and ties break by job id.
     """
     n_alloc = float(n_chips) if n_chips is not None else float(n_servers)
 
-    def rule(x_act, p):
-        x_seen = x_act if size_factors is None else x_act * size_factors
-        p_seen = p if p_hat is None else p_hat
-        theta = class_theta(name, x_seen, p_seen, n_servers=n_alloc, w=w)
-        return engine.finish_alloc(
-            theta, p, n_alloc=n_alloc, n_chips=n_chips, min_chips=min_chips,
-            snap_slices=snap_slices, dtype=dtype, lo=lo, hi=hi,
-        )
+    def make(job, tie):
+        factors, p_hat_, w_ = job["size_factors"], job["p_hat"], job["w"]
+        lo_, hi_ = job["lo"], job["hi"]
 
-    return rule
+        def rule(x_act, p):
+            x_seen = x_act if factors is None else x_act * factors
+            p_seen = p if p_hat_ is None else p_hat_
+            theta = class_theta(name, x_seen, p_seen, n_servers=n_alloc, w=w_)
+            return engine.finish_alloc(
+                theta, p, n_alloc=n_alloc, n_chips=n_chips, min_chips=min_chips,
+                snap_slices=snap_slices, dtype=dtype, lo=lo_, hi=hi_, tie=tie,
+            )
+
+        return rule
+
+    return engine.slotted_rule(make, {"size_factors": size_factors, "p_hat": p_hat,
+                                   "w": w, "lo": lo, "hi": hi})
 
 
 # ----------------------------------------------------- engine entry points
@@ -403,6 +413,20 @@ def simulate_multiclass(
     trace time, so K equal-``p`` classes reproduce the single-class engine
     bit-for-bit (property-tested in tests/test_multiclass.py).
     """
+    return _multiclass(
+        scn, classes=classes, policy=policy, n_servers=n_servers,
+        n_chips=n_chips, min_chips=min_chips, snap_slices=snap_slices,
+        rel_tol=rel_tol, horizon=horizon, estimator_kw=estimator_kw,
+    )
+
+
+def _multiclass(scn, *, classes, policy, n_servers, n_chips, min_chips,
+                snap_slices, rel_tol=1e-9, horizon=None, estimator_kw=None,
+                n_slots: int | None = None):
+    """:func:`simulate_multiclass`, or with ``n_slots`` the sweep's slot
+    pool: a whole-chip tape with no drift or estimator is then played
+    through ``arrivals.run_pooled``, its exponents, width limits, weights,
+    noise and class ids in the slots, and read as a ``PoolSimResult``."""
     specs = as_specs(classes) if classes is not None else None
     x0 = jnp.asarray(scn.x0)
     dtype = jnp.result_type(x0.dtype, jnp.float32)
@@ -418,6 +442,9 @@ def simulate_multiclass(
             "no estimator"
         )
     noiseless = scn.size_factors is None and scn.p_hat is None
+    pooled = (n_slots is not None and n_chips is not None
+              and scn.p_drift is None and estimator_kw is None)
+    n_classes = len(specs) if specs is not None and scn.class_ids is not None else 0
     if (
         p_shared is not None
         and noiseless
@@ -430,6 +457,13 @@ def simulate_multiclass(
         pol = make_policy(
             "hesrpt", n_servers=float(n_chips if n_chips is not None else n_servers)
         )
+        if pooled:
+            return run_pooled(
+                x0, arr, p_shared,
+                engine.quantized_rule(pol, n_chips, min_chips=min_chips, dtype=dtype),
+                n_slots=n_slots, n_alone=n_chips, rel_tol=rel_tol,
+                horizon=horizon, class_ids=scn.class_ids, n_classes=n_classes,
+            )
         if n_chips is None:
             return simulate_online(
                 x0, arr, p_shared, n_servers, pol, rel_tol=rel_tol, horizon=horizon
@@ -505,11 +539,17 @@ def simulate_multiclass(
             lo=lo,
             hi=hi,
         )
+    n_alone = n_chips if n_chips is not None else n_servers
+    if pooled:
+        return run_pooled(
+            x0, arr, p_job, rule, n_slots=n_slots, n_alone=n_alone,
+            rel_tol=rel_tol, horizon=horizon, class_ids=scn.class_ids,
+            n_classes=n_classes,
+        )
     res = engine.run(
         x0, arr, p_job, rule, horizon=horizon, rel_tol=rel_tol,
         p_drift=scn.p_drift,
     )
-    n_alone = n_chips if n_chips is not None else n_servers
     return _finalize(x0, arr, res.completion_times, p_job, n_alone)
 
 

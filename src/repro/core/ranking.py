@@ -32,20 +32,41 @@ def inv_rank(order: jax.Array) -> jax.Array:
     return jnp.argsort(order).astype(jnp.int32)
 
 
-def in_stable_prefix(key: jax.Array, order: jax.Array, k) -> jax.Array:
-    """``inv_rank(order) < k`` for a stable argsort ``order`` of ``key``.
+def stable_order(key: jax.Array, tie: jax.Array | None = None) -> jax.Array:
+    """Stable argsort of ``key``: equal keys in ``tie`` order, then by index.
 
-    Element ``i`` is among the first ``k`` iff ``k > 0`` and ``(key[i], i)
-    <= (key[j], j)`` lexicographically, where ``j = order[k - 1]`` (clipped
-    into range, so ``k >= M`` takes every element).  One gather and an
-    O(M) compare, no inverse permutation.  Exact for keys without NaN:
-    ``<`` and ``==`` agree with the sort's comparator on ±0.0 and ±inf.
+    ``tie`` is None on a finite tape, whose index already is the arrival
+    order, and then this is ``jnp.argsort(key)`` itself.  In a slot pool
+    the index is a slot's position, so the pool passes each slot's job id:
+    equal keys then keep the tape's order, as :func:`jnp.argsort` keeps it
+    on the tape.  One sort either way, with ``tie`` as a second key.
+    """
+    if tie is None:
+        return jnp.argsort(key)
+    idx = jnp.arange(key.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((key, tie, idx), num_keys=2, is_stable=True)[2]
+
+
+def in_stable_prefix(key: jax.Array, order: jax.Array, k,
+                     tie: jax.Array | None = None) -> jax.Array:
+    """``inv_rank(order) < k`` for an order ``stable_order(key, tie)``.
+
+    Element ``i`` is among the first ``k`` iff ``k > 0`` and ``(key[i],
+    tie[i], i) <= (key[j], tie[j], j)`` lexicographically (no ``tie`` term
+    when ``tie`` is None), where ``j = order[k - 1]`` (clipped into range,
+    so ``k >= M`` takes every element).  One gather and an O(M) compare, no
+    inverse permutation.  Exact for keys without NaN: ``<`` and ``==``
+    agree with the sort's comparator on ±0.0 and ±inf.
     """
     M = key.shape[0]
     j = order[jnp.clip(k - 1, 0, M - 1)]
     kj = key[j]
     idx = jnp.arange(M, dtype=order.dtype)
-    return (k > 0) & ((key < kj) | ((key == kj) & (idx <= j)))
+    if tie is None:
+        return (k > 0) & ((key < kj) | ((key == kj) & (idx <= j)))
+    tj = tie[j]
+    after = (tie < tj) | ((tie == tj) & (idx <= j))
+    return (k > 0) & ((key < kj) | ((key == kj) & after))
 
 
 def size_order_desc(x: jax.Array) -> jax.Array:

@@ -279,18 +279,18 @@ def stream_tape(scn: Scenario) -> tuple[jax.Array, jax.Array]:
     """Reduce a :class:`Scenario` to the plain ``(sizes, arrivals)`` tape
     the bounded-slot engine (``engine.run_stream``) consumes.
 
-    The streaming scan carries per-job state in *recycled slots*, so
-    scenario features that attach per-job vectors to the whole tape have
-    nothing to ride in: estimation noise (``size_factors``/``p_hat``),
-    per-job class exponents (``p_job``) and drift schedules (``p_drift``)
-    all raise here rather than silently dropping their physics.  Those
-    regimes stay on the finite-tape ``engine.run`` path until per-slot
-    state recycling grows to carry them.
+    The streaming regime plays plain tapes, so scenario features that
+    attach per-job vectors to the tape — estimation noise
+    (``size_factors``/``p_hat``), per-job class exponents (``p_job``) and
+    drift schedules (``p_drift``) — raise here rather than silently
+    dropping their physics.  Those regimes take the finite-tape sweep
+    path, whose whole-chip tapes carry noise and exponents in their slots
+    (``engine.run_stream``); the drift clock stays with ``engine.run``.
     """
     for field, why in (
         ("size_factors", "estimation noise is per-job tape state"),
         ("p_hat", "estimation noise is per-job tape state"),
-        ("p_job", "per-job class exponents do not ride in slots yet"),
+        ("p_job", "per-job class exponents take the finite-tape sweep"),
         ("p_drift", "the drift clock belongs to the finite-tape engine"),
     ):
         if getattr(scn, field) is not None:

@@ -22,6 +22,10 @@ is the single replacement path:
      a ``max_jobs_in_flight`` memory budget; a 2,000-jobs x 200-seeds x
      5-loads grid (2M simulated jobs per policy) runs on CPU without OOM.
      Chunked results are bit-for-bit the unchunked ``vmap`` (tested).
+     Whole-chip grids play each lane's tape through a pool of slots as wide
+     as the spec shows it needs (:func:`_pool_slots`), not through
+     ``[n_jobs]`` state, and play a call again on the full tape where a
+     pool overflowed.
   2. **Device sharding** — opt-in ``jax.shard_map`` over the seed or the
      rate axis, so multi-device hosts split the grid across devices;
      sharded == single-device bit for bit (tested under
@@ -101,6 +105,35 @@ STREAM_KEYS = ("n_slots", "warmup_frac", "end_frac")
 #: Estimation-regime arms (see ``benchmarks/estimation.py``): how the policy
 #: learns the speedup exponent on a p-drift scenario.
 ARMS = ("oracle", "stale", "estimator")
+
+#: What a lane played through a slot pool can report (``arrivals.PoolSimResult``):
+#: sums over the jobs as they finish, no per-job arrays.
+POOL_METRICS = ("total_flowtime", "mean_flowtime", "mean_slowdown", "makespan",
+                "class_flowtime", "class_slowdown")
+
+
+def _pool_slots(spec: "Sweep") -> int | None:
+    """Slots per lane for a whole-chip sweep's finite tapes, or None for the
+    full ``[n_jobs]`` scan.
+
+    A pool of W slots holds every job in flight as long as W is at least
+    their most; on whole chips at most ``n_chips`` jobs hold a chip, so W
+    is the smallest power of two at or above ``n_chips`` (the jobs queued
+    at 0 chips beyond them defer an arrival only past it, and a lane that
+    does is played again on the full tape, see :func:`run_sweep`).  The
+    full tape is kept where W would not be narrower than it, where every
+    job is in flight at once (the ``batch`` scenario), where the drift
+    clock runs (``drift_*``), and on the paths the pool does not carry: the
+    continuous regime, estimation arms, streams, probes, the fused
+    allocate, supersteps, and per-job metrics.
+    """
+    if (spec.n_chips is None or spec.arm is not None or spec.stream
+            or spec.telemetry or spec.fused or spec.superstep
+            or spec.scenario == "batch" or spec.scenario.startswith("drift_")
+            or any(m not in POOL_METRICS for m in spec.metrics)):
+        return None
+    w = 1 << max(spec.n_chips - 1, 0).bit_length()
+    return w if w < spec.n_jobs else None
 
 
 @functools.lru_cache(maxsize=1)
@@ -237,7 +270,7 @@ class Sweep(NamedTuple):
             if classes is not None or arm is not None:
                 raise ValueError(
                     "streaming sweeps are single-class and arm-free — "
-                    "per-job class/estimator state does not ride in slots"
+                    "the streaming regime plays plain tapes"
                 )
             skw_scn = dict(_hashable(scenario_kw or {}))
             if scenario.startswith(("drift_", "multiclass_")) or _any_pos(
@@ -416,8 +449,12 @@ def _check_limits(classes, n_chips: int | None, min_chips: int) -> None:
 
 
 # --------------------------------------------------------- per-cell functions
-def _cell_fn(spec: Sweep, name: str):
+def _cell_fn(spec: Sweep, name: str, slots: int | None = None):
     """Build ``one(key, rate) -> tuple_of_metrics`` for one policy.
+
+    With ``slots`` (see :func:`_pool_slots`) the whole-chip tapes are
+    played through a pool of that many slots, and the tuple ends with the
+    lane's overflow flag.
 
     These closures are verbatim ports of the per-experiment bodies this
     module replaced (the jit+vmap closures that lived in
@@ -464,6 +501,10 @@ def _cell_fn(spec: Sweep, name: str):
 
     @jax.named_scope("engine.reduce")
     def metrics_of(res, scn):
+        from repro.core.arrivals import PoolSimResult
+
+        if isinstance(res, PoolSimResult):
+            return tuple(getattr(res, m) for m in spec.metrics) + (res.overflow,)
         out = []
         for m in spec.metrics:
             if m in CLASS_METRICS:
@@ -552,7 +593,7 @@ def _cell_fn(spec: Sweep, name: str):
         return one
 
     if spec.classes is not None:
-        from repro.core.multiclass import simulate_multiclass
+        from repro.core.multiclass import _multiclass
 
         sampler = make_scenario(
             spec.scenario, size_alpha=spec.size_alpha, p=spec.p,
@@ -562,7 +603,7 @@ def _cell_fn(spec: Sweep, name: str):
         def one(key, rate):
             with jax.named_scope("engine.sample"):
                 scn = sampler(key, spec.n_jobs, rate)
-            res = simulate_multiclass(
+            res = _multiclass(
                 scn,
                 classes=spec.classes,
                 policy=name,
@@ -570,6 +611,7 @@ def _cell_fn(spec: Sweep, name: str):
                 n_chips=spec.n_chips,
                 min_chips=spec.min_chips,
                 snap_slices=spec.snap_slices,
+                n_slots=slots,
             )
             return metrics_of(res, scn)
 
@@ -616,9 +658,9 @@ def _cell_fn(spec: Sweep, name: str):
         return one
 
     from repro.core.arrivals import (
+        _scenario,
         simulate_online_ranked,
         simulate_online_superstep,
-        simulate_scenario,
     )
     from repro.core.policies import make_policy, make_rank_policy
     from repro.core.scenarios import _any_pos
@@ -663,10 +705,10 @@ def _cell_fn(spec: Sweep, name: str):
                 scn.x0, scn.arrival_times, spec.p, spec.n_servers, rank_pol
             )
         else:
-            res = simulate_scenario(
+            res = _scenario(
                 scn, spec.p, spec.n_servers, pol, n_chips=spec.n_chips,
                 min_chips=spec.min_chips, fused=spec.fused,
-                telemetry=tel_probe,
+                telemetry=tel_probe, n_slots=slots,
             )
             if tel_probe is not None:
                 res, tel = res
@@ -692,17 +734,20 @@ def _out_names(spec: Sweep) -> tuple[str, ...]:
 
 def _build_fn(
     spec: Sweep, name: str, chunk: int | None, shard: bool,
-    shard_axis: str = "seeds",
+    shard_axis: str = "seeds", slots: int | None = None,
 ):
     """The pure ``(keys, rates) -> tuple_of_metric_arrays`` a policy runs.
 
     ``keys`` (or ``rates``, under ``shard_axis="rates"``) may be padded to
     the shard grid; each metric comes back ``[n_rates, len(keys)(, K)]``.
+    With ``slots`` the lanes play through a slot pool and one more array,
+    each lane's overflow flag, ends the tuple.
     """
     import jax
     import jax.numpy as jnp
 
-    one = _cell_fn(spec, name)
+    one = _cell_fn(spec, name, slots)
+    names = _out_names(spec) + (() if slots is None else ("overflow",))
 
     def inner(keys, rates):
         # One vmap over the flat (rate, seed) lanes, rate-major, not a
@@ -752,14 +797,12 @@ def _build_fn(
         # arrays are [n_rates, n_seeds(, K)], so the sharded axis leads.
         in_specs = (P(), P("rates"))
         out_specs = tuple(
-            P("rates", None, *(None,) * _metric_ndim(spec, m))
-            for m in _out_names(spec)
+            P("rates", None, *(None,) * _metric_ndim(spec, m)) for m in names
         )
     else:
         in_specs = (P("seeds"), P())
         out_specs = tuple(
-            P(None, "seeds", *(None,) * _metric_ndim(spec, m))
-            for m in _out_names(spec)
+            P(None, "seeds", *(None,) * _metric_ndim(spec, m)) for m in names
         )
 
     def sharded(keys, rates):
@@ -787,31 +830,37 @@ def _build_fn(
 # distinct configs plateau instead of accumulating executables forever.
 _EXECUTORS: dict[tuple, Any] = {}
 _EXECUTORS_MAX = 64
+# The full-tape executors of pooled specs, compiled only once a pool has
+# overflowed (:func:`run_sweep`); kept apart so that ``_EXECUTORS`` holds
+# one executor per spec and policy, the one each call runs first.
+_FALLBACKS: dict[tuple, Any] = {}
 
 
 def _executor(spec: Sweep, name: str, keys, rates, chunk: int | None,
-              shard: bool, shard_axis: str = "seeds"):
+              shard: bool, shard_axis: str = "seeds", slots: int | None = None,
+              cache: dict | None = None):
     """Return ``(compiled, compile_seconds)`` for one policy column."""
     import jax
 
+    cache = _EXECUTORS if cache is None else cache
     cache_key = (
         spec._replace(policies=()), name, int(keys.shape[0]),
         int(rates.shape[0]), chunk, shard, shard_axis,
-        str(keys.dtype), str(rates.dtype),
+        str(keys.dtype), str(rates.dtype), slots,
     )
-    hit = _EXECUTORS.get(cache_key)
+    hit = cache.get(cache_key)
     if hit is not None:
         # LRU refresh: re-insert so hot executors survive the eviction
         # sweep below (dict preserves insertion order).
-        _EXECUTORS[cache_key] = _EXECUTORS.pop(cache_key)
+        cache[cache_key] = cache.pop(cache_key)
         return hit, 0.0
-    f = _build_fn(spec, name, chunk, shard, shard_axis)
+    f = _build_fn(spec, name, chunk, shard, shard_axis, slots)
     t0 = time.perf_counter()
     compiled = jax.jit(f).lower(keys, rates).compile()
     compile_s = time.perf_counter() - t0
-    while len(_EXECUTORS) >= _EXECUTORS_MAX:
-        _EXECUTORS.pop(next(iter(_EXECUTORS)))
-    _EXECUTORS[cache_key] = compiled
+    while len(cache) >= _EXECUTORS_MAX:
+        cache.pop(next(iter(cache)))
+    cache[cache_key] = compiled
     return compiled, compile_s
 
 
@@ -1022,6 +1071,13 @@ def run_sweep(
     for very wide load grids with few seeds (the accelerator-lane shape,
     ``benchmarks/backend_lane.py``).  ``log=False`` keeps the run out of
     :data:`RUN_LOG` (used by tests).
+
+    Whole-chip sweeps play each lane's tape through a pool of slots, as
+    wide as :func:`_pool_slots` reads off the spec, instead of the whole
+    tape.  A call in which any lane's pool overflowed is played again on
+    the full-tape executor, compiled then and not before, and counted as
+    ``engine.pool_overflows`` (``repro.tracing.counts()``): the numbers
+    are the full tape's either way.
     """
     import jax
     import jax.numpy as jnp
@@ -1054,12 +1110,14 @@ def run_sweep(
             if chunk is not None and chunk >= s_pad // n_dev:
                 chunk = None  # one chunk == the plain vmap; share its executor
 
+    slots = _pool_slots(spec)
     stats: dict[str, dict[str, np.ndarray]] = {}
     compile_s = 0.0
     wall_s = 0.0
     for step, name in enumerate(spec.policies):
         with tracing.span("sweep.executor", request=call):
-            f, c_s = _executor(spec, name, keys, rates, chunk, shard, shard_axis)
+            f, c_s = _executor(spec, name, keys, rates, chunk, shard, shard_axis,
+                               slots)
         compile_s += c_s
         t0 = time.perf_counter()
         # StepTraceAnnotation is a no-op unless a jax.profiler trace is
@@ -1082,6 +1140,15 @@ def run_sweep(
                     jax.block_until_ready(out)
             with tracing.span("sweep.fetch", request=call):
                 out = tuple(np.asarray(a) for a in out)
+        if slots is not None:
+            *out, overflow = out
+            if overflow.any():
+                tracing.count("engine.pool_overflows")
+                with tracing.span("sweep.executor", request=call):
+                    f, c_s = _executor(spec, name, keys, rates, chunk, shard,
+                                       shard_axis, cache=_FALLBACKS)
+                compile_s += c_s
+                out = tuple(np.asarray(a) for a in f(keys, rates))
         wall_s += time.perf_counter() - t0
         stats[name] = {
             m: a[:R, :S] for m, a in zip(_out_names(spec), out, strict=True)

@@ -20,7 +20,10 @@ The compile counter is always on: a ``jax.monitoring`` listener keeps
 every tracing, lowering and backend-compile event (seconds, the function's
 name, end on ``time.perf_counter``).  They fire only when something
 compiles; on jax 0.9 a persistent-cache hit fires them too, with the
-backend step then the cache read.  ``compiles()`` reads them.
+backend step then the cache read.  ``compiles()`` reads them.  Beside it,
+``count(name)`` bumps a named counter, also always on, which ``counts()``
+reads: ``engine.pool_overflows`` counts the sweep calls a slot pool could
+not hold, played again on the full tape (``core/sweeps.py``).
 
 Spans placed in the program (each span's metric is named in PERF.md):
 
@@ -73,6 +76,7 @@ class Compile(NamedTuple):
 
 _spans: collections.deque[Span] = collections.deque(maxlen=MAX_RECORDS)
 _compiles: collections.deque[Compile] = collections.deque(maxlen=MAX_RECORDS)
+_counts: collections.Counter[str] = collections.Counter()
 _ids = itertools.count()
 _open = threading.local()  # .stack: the spans open on this thread
 _enabled = jax.profiler.TraceAnnotation.is_enabled
@@ -145,10 +149,21 @@ def compiles() -> list[Compile]:
     return list(_compiles)
 
 
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the counter ``name``."""
+    _counts[name] += n
+
+
+def counts() -> dict[str, int]:
+    """Every counter's value so far."""
+    return dict(_counts)
+
+
 def clear() -> None:
-    """Forget every span and compile event kept so far."""
+    """Forget every span, compile event and count kept so far."""
     _spans.clear()
     _compiles.clear()
+    _counts.clear()
 
 
 def _on_duration(event: str, duration_secs: float, **kwargs) -> None:

@@ -292,11 +292,14 @@ def test_simulate_stream_quantized_plumbing():
 
 # ------------------------------------------------------------- validation
 def test_stream_rejects_per_job_p():
+    # A tape's per-job exponents ride in the slots (run_stream); a source
+    # brings them with its arrivals, and the carried-rank scan needs one p.
     x0, arr = _tape(seed=0, n_jobs=8)
     rule, _ = _rule("continuous", x0.dtype)
     p_job = jnp.full(8, 0.5)
+    src = engine.poisson_source(jax.random.key(0), 1.5, dtype=x0.dtype)
     with pytest.raises(ValueError, match="scalar p"):
-        engine.run_stream(x0, arr, p_job, rule, n_slots=8)
+        engine.run_stream_source(src, p_job, rule, n_slots=8, n_events=16)
     with pytest.raises(ValueError, match="scalar p"):
         engine.run_stream_ranked(x0, arr, p_job, 1.0,
                                  make_rank_policy("hesrpt"), n_slots=8)
@@ -317,3 +320,141 @@ def test_stream_tape_rejects_non_slot_state():
 def test_window_is_stream_mode_only():
     with pytest.raises(ValueError, match="stream-mode only"):
         make_probe(("utilization",), mode="series", window=(0.0, 1.0))
+
+
+# ------------------------------------------- finite tapes through a slot pool
+def _pool_case(kind):
+    """A seeded tape, a whole-chip rule over it and the pool width the
+    sweep would pick (``sweeps._pool_slots``), at a load whose most jobs
+    in flight the pool holds."""
+    from repro.core import multiclass as mc
+
+    dtype = jnp.result_type(float)
+    if kind == "capped_snap":
+        classes = (mc.ClassSpec(p=0.3, mix=0.8, min_chips=1, max_chips=8),
+                   mc.ClassSpec(p=0.6, mix=0.17, size_scale=8.0, min_chips=8,
+                                max_chips=64),
+                   mc.ClassSpec(p=0.9, mix=0.03, size_scale=64.0, min_chips=64,
+                                max_chips=256))
+        scn = make_scenario("multiclass_poisson", classes=classes)(
+            jax.random.key(11), 400, 9.99)
+        order = jnp.argsort(scn.arrival_times)
+        lo, hi = mc.job_limits(classes, scn.class_ids, n_chips=256)
+        rule = mc.class_rule("hesrpt_pc", n_servers=256.0, n_chips=256,
+                             snap_slices=True, dtype=dtype, lo=lo[order],
+                             hi=hi[order])
+        return scn, scn.p_job, rule, 256, scn.class_ids, len(classes)
+    scn = make_scenario("poisson", p=0.5, sigma_size=0.3 if kind == "noisy" else 0.0)(
+        jax.random.key(7), 120, 2.0)
+    factors = None
+    if kind == "noisy":
+        factors = scn.size_factors[jnp.argsort(scn.arrival_times)]
+    rule = engine.quantized_rule(make_policy("hesrpt"), 16, min_chips=2, dtype=dtype,
+                                 size_factors=factors)
+    return scn, 0.5, rule, 16, None, 0
+
+
+@pytest.mark.parametrize("kind", ["quantized_min_chips", "capped_snap", "noisy"])
+def test_pooled_tape_equals_the_full_tape(kind):
+    from repro.core.analysis import per_class_mean
+    from repro.core.arrivals import _finalize, run_pooled
+
+    scn, p, rule, n_slots, cls, n_classes = _pool_case(kind)
+    x0, arr = scn.x0, scn.arrival_times
+    assert n_slots < x0.shape[0]
+    ref = engine.run(x0, arr, p, rule)
+    res = engine.run_stream(x0, arr, p, rule, n_slots=n_slots, n_alone=n_slots,
+                            record_times=True, class_ids=cls, n_classes=n_classes)
+    assert int(res.blocked_steps) == 0 and 1 < int(res.occupancy_max) <= n_slots
+    # The trajectory: every job leaves at the same time, bit for bit.
+    np.testing.assert_array_equal(np.asarray(res.completion_times),
+                                  np.asarray(ref.completion_times))
+    # The pooled read-out sums the same per-job values as they finish; only
+    # the order of the additions differs from the full tape's reduction.
+    full = _finalize(x0, arr, ref.completion_times, p, n_slots)
+    pooled = run_pooled(x0, arr, p, rule, n_slots=n_slots, n_alone=n_slots,
+                        rel_tol=1e-9, horizon=None, class_ids=cls,
+                        n_classes=n_classes)
+    assert not bool(pooled.overflow)
+    for m in ("mean_flowtime", "mean_slowdown", "total_flowtime", "makespan"):
+        np.testing.assert_allclose(float(getattr(pooled, m)), float(getattr(full, m)),
+                                   rtol=1e-13, err_msg=m)
+    if cls is not None:
+        for m, per_job in (("class_flowtime", full.flow_times),
+                           ("class_slowdown", full.slowdowns)):
+            np.testing.assert_allclose(np.asarray(getattr(pooled, m)),
+                                       np.asarray(per_class_mean(per_job, cls, n_classes)),
+                                       rtol=1e-13, err_msg=m)
+
+
+def test_overflowing_pool_is_played_again_on_the_full_tape(monkeypatch):
+    from repro import tracing
+    from repro.core import sweeps
+
+    spec = sweeps.Sweep.create(("hesrpt", "equi"), (2.0, 6.0), n_jobs=60, n_seeds=2,
+                               p=0.5, n_servers=16.0, n_chips=16, min_chips=2,
+                               metrics=("mean_flowtime", "mean_slowdown"))
+    assert sweeps._pool_slots(spec) == 16
+    monkeypatch.setattr(sweeps, "_EXECUTORS", {})
+    monkeypatch.setattr(sweeps, "_FALLBACKS", {})
+    monkeypatch.setattr(sweeps, "_pool_slots", lambda s: None)
+    full = sweeps.run_sweep(spec, log=False)
+    # A pool of 3 slots overflows at these loads: each policy's call is
+    # played again on the full-tape executor, compiled only then.
+    monkeypatch.setattr(sweeps, "_pool_slots", lambda s: 3)
+    before = tracing.counts().get("engine.pool_overflows", 0)
+    sweeps._EXECUTORS.clear()
+    again = sweeps.run_sweep(spec, log=False)
+    assert tracing.counts()["engine.pool_overflows"] - before == 2
+    assert len(sweeps._FALLBACKS) == 2
+    for name in spec.policies:
+        for m in spec.metrics:
+            np.testing.assert_array_equal(again.stats[name][m], full.stats[name][m])
+    # A pool that holds compiles no fallback and counts nothing.
+    monkeypatch.setattr(sweeps, "_pool_slots", lambda s: 16)
+    monkeypatch.setattr(sweeps, "_FALLBACKS", {})
+    before = tracing.counts().get("engine.pool_overflows", 0)
+    light = sweeps.Sweep.create(("hesrpt",), (1.0,), n_jobs=60, n_seeds=2, p=0.5,
+                                n_servers=16.0, n_chips=16, min_chips=2)
+    sweeps.run_sweep(light, log=False)
+    assert tracing.counts().get("engine.pool_overflows", 0) == before
+    assert sweeps._FALLBACKS == {}
+
+
+@pytest.mark.parametrize("kw", [
+    dict(n_chips=None),  # continuous rules (the carried-rank scan)
+    dict(fused=True),
+    dict(telemetry=("utilization",)),
+    dict(scenario="batch"),  # every job in flight at once
+    dict(scenario="drift_poisson", scenario_kw={"p0": 0.8}),
+    dict(n_jobs=16),  # no narrower than the tape
+    dict(metrics=("flow_times",)),  # a per-job metric
+])
+def test_bypassed_sweeps_keep_the_full_tape(kw):
+    from repro.core import sweeps
+
+    base = dict(n_jobs=600, n_seeds=2, p=0.5, n_servers=256.0, n_chips=256)
+    assert sweeps._pool_slots(sweeps.Sweep.create(("hesrpt",), (76.8,), **base)) == 256
+    spec = sweeps.Sweep.create(("hesrpt",), (76.8,), **{**base, **kw})
+    assert sweeps._pool_slots(spec) is None
+
+
+@pytest.mark.parametrize("kw", [dict(fused=True), dict(telemetry="probe"),
+                                dict(n_chips=None), dict(drift=True)])
+def test_bypassed_simulations_keep_the_full_tape(kw):
+    from repro.core.arrivals import OnlineSimResult, PoolSimResult, _scenario
+
+    scn = make_scenario("poisson", p=0.5)(jax.random.key(0), 40, 2.0)
+    args = dict(n_chips=16, min_chips=1, rel_tol=1e-9, horizon=None, fused=False,
+                telemetry=None)
+    pol = make_policy("hesrpt")
+    assert isinstance(_scenario(scn, 0.5, 16.0, pol, n_slots=8, **args), PoolSimResult)
+    if kw.pop("drift", False):
+        scn = scn._replace(p_drift=engine.PDrift(times=jnp.asarray([5.0]),
+                                                 values=jnp.asarray([0.5, 0.3])))
+    probe = kw.get("telemetry") == "probe"
+    if probe:
+        kw["telemetry"] = make_probe(("utilization",), mode="stream", n_jobs=40,
+                                     dtype=scn.x0.dtype)
+    res = _scenario(scn, 0.5, 16.0, pol, n_slots=8, **{**args, **kw})
+    assert isinstance(res[0] if probe else res, OnlineSimResult)
