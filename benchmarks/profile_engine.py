@@ -25,7 +25,7 @@ optimization trajectory this repo shipped:
 
 Wall times come from ``jax.block_until_ready`` over jitted calls; sort
 counts are *measured from the compiled HLO* via
-``launch.hlo_analysis.op_histogram`` (trip-count-aware, so the full
+``core.hlo.op_histogram`` (trip-count-aware, so the full
 ``engine.run`` scan reports sorts *per event*, not per program).  The
 headline acceptance number is the fused-vs-seed per-event allocate
 speedup on CPU (target >= 1.5x, driven by the sort-count reduction).
@@ -137,7 +137,7 @@ def _sort_count(f, *args) -> float:
     """``sort`` ops in the compiled HLO (while bodies x trip count)."""
     import jax
 
-    from repro.launch.hlo_analysis import op_histogram
+    from repro.core.hlo import op_histogram
 
     hlo = jax.jit(f).lower(*args).compile().as_text()
     return op_histogram(hlo).get("sort", 0.0)

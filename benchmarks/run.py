@@ -5,7 +5,8 @@ benches.  ``python -m benchmarks.run [--quick] [--smoke]
 ``--quick`` shrinks the expensive sweeps; ``--smoke`` is the CI tier-1
 gate: every section that exercises the allocation engine runs at tiny
 sizes (seconds, not minutes) so the sweeps cannot silently rot, and the
-long-running extras (speedup timings, kernel micro-bench) are skipped.
+long-running extra (the scheduler's decision cost at cluster scale) is
+skipped.
 
 ``--profile-dir DIR`` wraps the whole run in ``jax.profiler.start_trace``:
 the ``StepTraceAnnotation`` markers ``core/sweeps.py`` emits around each
@@ -23,8 +24,7 @@ import jax
 
 from repro.compile_cache import enable_compile_cache
 
-# Scheduler math (closed forms vs simulation) wants f64; model/kernel code
-# pins its own dtypes explicitly so this only affects the core benchmarks.
+# Scheduler math (closed forms vs simulation) wants f64.
 jax.config.update("jax_enable_x64", True)
 # The benchmark sections recompile the same engine scans every run.
 enable_compile_cache()
@@ -134,12 +134,6 @@ def main() -> None:
         from benchmarks import sched_scale
 
         text, _ = sched_scale.main()
-        print(text)
-
-        _section("Beyond paper — kernel micro-bench (CPU; TPU story = roofline)")
-        from benchmarks import kernels_bench
-
-        text, _ = kernels_bench.main()
         print(text)
 
     # Every run_sweep call above logged a structured record (spec, per-cell

@@ -27,7 +27,7 @@ Two accumulation modes, one probe contract (:class:`Probe` — ``init`` /
 - ``mode="series"`` — the full per-event time series ``[E]`` per metric
   (plus epoch starts and lengths).  Memory is O(events × metrics): right
   for ``record=True``-sized runs, and what the Perfetto exporter
-  (``launch/trace_export.py``) turns into counter tracks.
+  (``core/trace_export.py``) turns into counter tracks.
 - ``mode="stream"`` — O(1) streaming aggregates carried through the scan:
   time-weighted means (``Σ m·dt / Σ dt``), maxima over positive-length
   epochs, and fixed-bin time-weighted histograms via scatter-add

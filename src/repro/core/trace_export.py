@@ -1,6 +1,6 @@
 """Chrome trace-event (Perfetto) export of engine schedules.
 
-    python -m repro.launch.trace_export --out schedule_trace.json
+    python -m repro.core.trace_export --out schedule_trace.json
 
 Converts a recorded engine trajectory (``engine.run(record=True)``) into
 the Chrome trace-event JSON format that https://ui.perfetto.dev and

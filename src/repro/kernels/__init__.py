@@ -1,20 +1,11 @@
-"""Pallas TPU kernels (+ jnp oracles) for the substrate's compute hot-spots.
+"""The fused whole-chip allocate: heSRPT ranks -> Thm-7 brackets -> chips.
 
-The paper's contribution (heSRPT) is kernel-free scheduler math; these kernels
-serve the *scheduled substrate*: flash attention (causal/SWA/GQA), the Mamba2
-SSD chunked scan, and the RG-LRU linear recurrence.
+``alloc.py`` holds the Pallas kernel (no sorts: positions from comparison
+counting) and its pure-jnp reference.  ``engine.quantized_rule`` and
+``engine.continuous_rule`` attach it to heSRPT rules as ``fused_variant``;
+``engine.run(fused=True)`` and ``Sweep(fused=True)`` select it.
 """
 
-from repro.kernels import alloc, ops, ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.rglru_scan import rglru_scan
-from repro.kernels.ssd_scan import ssd_scan
+from repro.kernels import alloc
 
-__all__ = [
-    "alloc",
-    "flash_attention",
-    "ops",
-    "ref",
-    "rglru_scan",
-    "ssd_scan",
-]
+__all__ = ["alloc"]

@@ -1,6 +1,6 @@
 """Fused heSRPT allocation: ranks -> Thm-7 brackets -> whole chips, one pass.
 
-This is the kernel that finally connects the Pallas stack to the scheduling
+This is the repo's one Pallas kernel, a fast path of the scheduling
 core.  The engine's per-event hot path (``core/engine.py``) spends its time
 deriving the *same* sorted order over and over: the policy sorts remaining
 sizes for the descending ranks, then ``quantize_allocation_jax`` sorts theta
@@ -30,7 +30,7 @@ O(M^2) comparison counting — ``pos_i = #{j : key_j < key_i or (key_j ==
 key_i and j < i)}`` — which reproduces a *stable* argsort's positions as
 exact integers, chunked over columns so the pairwise tile stays small.  The
 whole job vector lives in VMEM (single program, no grid): an [M] f32/f64
-vector is tiny next to the matmul workloads the other kernels tile.
+vector is tiny next to VMEM, so nothing is tiled.
 
 Exactness caveats (documented, property-tested):
 
@@ -45,7 +45,7 @@ Exactness caveats (documented, property-tested):
   (the oversubscription renormalizer), which can move chips on knife-edge
   inputs.  The ref path keeps the unpadded reduction and is bit-exact.
 
-``impl`` follows ``kernels/ops.py``: ``auto`` (pallas on TPU, ref
+``impl`` picks the path: ``auto`` (pallas on TPU, ref
 elsewhere), ``ref``, ``pallas``, ``interpret``.
 """
 

@@ -1,17 +1,13 @@
 """Cluster scheduler built on the paper's policies: quantization, online
-p-estimation, decision epochs, elastic resizing, straggler mitigation."""
+p-estimation, decision epochs, straggler mitigation."""
 
 from repro.sched.cluster import ClusterScheduler, Job
-from repro.sched.elastic import ElasticClusterDriver, ElasticJob, ElasticJobConfig
 from repro.sched.estimator import SpeedupEstimator, blended_p, pooled_p_hat
 from repro.sched.quantize import quantize_allocation, snap_to_slices
 from repro.sched.stragglers import StragglerDetector
 
 __all__ = [
     "ClusterScheduler",
-    "ElasticClusterDriver",
-    "ElasticJob",
-    "ElasticJobConfig",
     "Job",
     "SpeedupEstimator",
     "StragglerDetector",

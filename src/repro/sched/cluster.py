@@ -31,8 +31,8 @@ Python-only policy path — now delegates too (``core.engine.knee_rule``
 recomputes the masked median inside the scan).  The per-event Python path
 (``allocations`` / ``advance_fluid``) remains both oracle and fallback
 for heterogeneous p without ``class_aware`` (and KNEE under
-``use_estimator``); ``sched/elastic.py`` uses it to drive real training
-jobs through ``report_progress``.
+``use_estimator``).  Running jobs report observed throughput through
+``report_progress``.
 """
 
 from __future__ import annotations

@@ -13,7 +13,7 @@
   sweep output (the same array tests/test_sweeps.py pins for the unfused
   path) — the fused engine changes the op schedule, never the numbers.
 - **Sort counts**: the optimization's whole point, measured from compiled
-  HLO via ``launch.hlo_analysis.op_histogram`` — 2 sorts for the policy
+  HLO via ``core.hlo.op_histogram`` — 2 sorts for the policy
   (the size order and its inverse permutation), 4 for the unfused
   allocate, 3 fused, 0 for the Pallas kernel, and the
   engine's scan body pays exactly one fewer sort per event when fused.
@@ -152,10 +152,11 @@ def test_engine_fused_rejects_rules_without_variant():
 
 
 # ----------------------------------------------------------------- golden pin
-# The pre-refactor quantized sweep output pinned in tests/test_sweeps.py
-# (GOLDEN_QUANTIZED there): the fused engine must reproduce it bit-for-bit.
+# The quantized sweep output pinned in tests/test_sweeps.py (GOLDEN_QUANTIZED
+# there, re-pinned on jax 0.9's draws): the fused engine must reproduce it
+# bit-for-bit.
 GOLDEN_QUANTIZED_FUSED = np.array([
-    [0.7648913378555785, 0.6046536432011128, 0.6815494191735356],
+    [0.7942090697062641, 0.6759029764795415, 0.7153949192387922],
 ])
 
 
@@ -183,7 +184,7 @@ def test_sweep_fused_requires_hesrpt_quantized():
 
 # ---------------------------------------------------------------- sort counts
 def _sorts(f, *args) -> float:
-    from repro.launch.hlo_analysis import op_histogram
+    from repro.core.hlo import op_histogram
 
     hlo = jax.jit(f).lower(*args).compile().as_text()
     return op_histogram(hlo).get("sort", 0.0)

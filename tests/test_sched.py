@@ -1,19 +1,12 @@
-"""Scheduler-layer unit tests: cluster epochs, arrivals, stragglers,
-compression math, data pipeline determinism."""
+"""Scheduler-layer unit tests: cluster epochs, arrivals, stragglers."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import hesrpt_total_flowtime, optimal_makespan
-from repro.data.pipeline import DataConfig, ShardedSyntheticStream
 from repro.sched import ClusterScheduler, Job, StragglerDetector
 from repro.sched.estimator import SpeedupEstimator, blended_p
-from repro.train.compression import (
-    compress_psum_int8,
-    compress_psum_topk,
-    init_error_state,
-)
 
 
 @pytest.mark.slow
@@ -75,68 +68,6 @@ def test_straggler_detector_flags_slow_job():
 def test_blended_p_work_weighted():
     a, b = SpeedupEstimator(prior_p=0.2), SpeedupEstimator(prior_p=0.8)
     assert abs(blended_p([a, b], [3.0, 1.0]) - (0.2 * 3 + 0.8) / 4) < 1e-9
-
-
-# ------------------------------------------------------------- compression
-def test_int8_compression_error_feedback_converges():
-    """With error feedback, the time-averaged compressed gradient converges
-    to the true gradient (single 'device': psum over trivial axis)."""
-    import jax
-
-    g_true = {"w": jnp.asarray(np.random.default_rng(0).standard_normal(64),
-                               jnp.float32)}
-    err = init_error_state(g_true)
-
-    def one(err):
-        return compress_psum_int8(g_true, err, "i")
-
-    f = jax.jit(lambda e: jax.vmap(lambda _, e: one(e), in_axes=(0, None),
-                                   axis_name="i")(jnp.arange(1), e))
-    acc = jnp.zeros(64)
-    for _ in range(50):
-        out, err = f(err)
-        out = jax.tree.map(lambda x: x[0], out)
-        err = jax.tree.map(lambda x: x[0], err)
-        acc = acc + out["w"]
-    np.testing.assert_allclose(np.asarray(acc / 50), np.asarray(g_true["w"]),
-                               atol=1e-3)
-
-
-def test_topk_compression_keeps_largest():
-    import jax
-
-    g = {"w": jnp.asarray([0.1, -5.0, 0.2, 4.0, 0.0, 0.05], jnp.float32)}
-    err = init_error_state(g)
-
-    def run(g, e):
-        return compress_psum_topk(g, e, "i", k_frac=0.34)
-
-    out, new_err = jax.vmap(lambda _: run(g, jax.tree.map(lambda x: x, err)),
-                            axis_name="i")(jnp.arange(1))
-    w = np.asarray(out["w"][0])
-    assert w[1] != 0 and w[3] != 0  # two largest kept
-    assert np.count_nonzero(w) == 2
-    # error feedback holds the dropped mass
-    np.testing.assert_allclose(np.asarray(new_err["w"][0]),
-                               np.asarray(g["w"]) - w, atol=1e-6)
-
-
-# ---------------------------------------------------------------- pipeline
-def test_pipeline_deterministic_and_host_sharded():
-    cfg = DataConfig(vocab_size=97, seq_len=16, global_batch=8, seed=3)
-    a = ShardedSyntheticStream(cfg, host_id=0, n_hosts=2).batch(5)
-    b = ShardedSyntheticStream(cfg, host_id=0, n_hosts=2).batch(5)
-    c = ShardedSyntheticStream(cfg, host_id=1, n_hosts=2).batch(5)
-    np.testing.assert_array_equal(a["tokens"], b["tokens"])  # deterministic
-    assert not np.array_equal(a["tokens"], c["tokens"])  # host-sharded
-    assert a["tokens"].shape == (4, 16)
-    # labels are next-token shifted along the affine chain
-    np.testing.assert_array_equal(
-        a["labels"][:, :-1], a["tokens"][:, 1:]
-    )
-    np.testing.assert_array_equal(
-        a["labels"], (31 * a["tokens"].astype(np.int64) + 7) % 97
-    )
 
 
 def test_arrival_stream_hesrpt_dominates():

@@ -323,70 +323,8 @@ def test_window_is_stream_mode_only():
 
 
 # ------------------------------------------- finite tapes through a slot pool
-def _pool_case(kind):
-    """A seeded tape, a whole-chip rule over it and the pool width the
-    sweep would pick (``sweeps._pool_slots``), at a load whose most jobs
-    in flight the pool holds."""
-    from repro.core import multiclass as mc
-
-    dtype = jnp.result_type(float)
-    if kind == "capped_snap":
-        classes = (mc.ClassSpec(p=0.3, mix=0.8, min_chips=1, max_chips=8),
-                   mc.ClassSpec(p=0.6, mix=0.17, size_scale=8.0, min_chips=8,
-                                max_chips=64),
-                   mc.ClassSpec(p=0.9, mix=0.03, size_scale=64.0, min_chips=64,
-                                max_chips=256))
-        scn = make_scenario("multiclass_poisson", classes=classes)(
-            jax.random.key(11), 400, 9.99)
-        order = jnp.argsort(scn.arrival_times)
-        lo, hi = mc.job_limits(classes, scn.class_ids, n_chips=256)
-        rule = mc.class_rule("hesrpt_pc", n_servers=256.0, n_chips=256,
-                             snap_slices=True, dtype=dtype, lo=lo[order],
-                             hi=hi[order])
-        return scn, scn.p_job, rule, 256, scn.class_ids, len(classes)
-    scn = make_scenario("poisson", p=0.5, sigma_size=0.3 if kind == "noisy" else 0.0)(
-        jax.random.key(7), 120, 2.0)
-    factors = None
-    if kind == "noisy":
-        factors = scn.size_factors[jnp.argsort(scn.arrival_times)]
-    rule = engine.quantized_rule(make_policy("hesrpt"), 16, min_chips=2, dtype=dtype,
-                                 size_factors=factors)
-    return scn, 0.5, rule, 16, None, 0
-
-
-@pytest.mark.parametrize("kind", ["quantized_min_chips", "capped_snap", "noisy"])
-def test_pooled_tape_equals_the_full_tape(kind):
-    from repro.core.analysis import per_class_mean
-    from repro.core.arrivals import _finalize, run_pooled
-
-    scn, p, rule, n_slots, cls, n_classes = _pool_case(kind)
-    x0, arr = scn.x0, scn.arrival_times
-    assert n_slots < x0.shape[0]
-    ref = engine.run(x0, arr, p, rule)
-    res = engine.run_stream(x0, arr, p, rule, n_slots=n_slots, n_alone=n_slots,
-                            record_times=True, class_ids=cls, n_classes=n_classes)
-    assert int(res.blocked_steps) == 0 and 1 < int(res.occupancy_max) <= n_slots
-    # The trajectory: every job leaves at the same time, bit for bit.
-    np.testing.assert_array_equal(np.asarray(res.completion_times),
-                                  np.asarray(ref.completion_times))
-    # The pooled read-out sums the same per-job values as they finish; only
-    # the order of the additions differs from the full tape's reduction.
-    full = _finalize(x0, arr, ref.completion_times, p, n_slots)
-    pooled = run_pooled(x0, arr, p, rule, n_slots=n_slots, n_alone=n_slots,
-                        rel_tol=1e-9, horizon=None, class_ids=cls,
-                        n_classes=n_classes)
-    assert not bool(pooled.overflow)
-    for m in ("mean_flowtime", "mean_slowdown", "total_flowtime", "makespan"):
-        np.testing.assert_allclose(float(getattr(pooled, m)), float(getattr(full, m)),
-                                   rtol=1e-13, err_msg=m)
-    if cls is not None:
-        for m, per_job in (("class_flowtime", full.flow_times),
-                           ("class_slowdown", full.slowdowns)):
-            np.testing.assert_allclose(np.asarray(getattr(pooled, m)),
-                                       np.asarray(per_class_mean(per_job, cls, n_classes)),
-                                       rtol=1e-13, err_msg=m)
-
-
+# The pool's equality with the full tape, policy by policy, is the path
+# contract of tests/test_contract_pool.py and test_contract_pool_classes.py.
 def test_overflowing_pool_is_played_again_on_the_full_tape(monkeypatch):
     from repro import tracing
     from repro.core import sweeps

@@ -7,7 +7,7 @@ Two layers:
   and within budget at every event, remaining sizes non-increasing per
   job, event times ordered, and each job's last positive-size epoch
   consistent with its reported completion time;
-- ``launch/trace_export.py`` must turn that trace into *valid* Chrome
+- ``core/trace_export.py`` must turn that trace into *valid* Chrome
   trace-event JSON (the committed sample artifact included): slices only
   while a job holds an allocation, one completion marker per finished job
   at exactly its completion time, counter tracks present, and the schema
@@ -21,9 +21,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import engine, make_policy, make_scenario
+from repro.core import engine, make_policy, make_scenario, trace_export
 from repro.core.telemetry import DEFAULT_METRICS, make_probe
-from repro.launch import trace_export
 
 N_JOBS = 24
 SAMPLE = Path(__file__).parent.parent / "examples" / "sample_schedule_trace.json"
