@@ -1410,7 +1410,14 @@ def quantize_allocation_jax(
     - **Oversubscription** (more active jobs than ``n_chips // min_chips``
       can hold): keep the largest-theta jobs, queue the rest at 0 chips,
       renormalize.  The oracle recurses once; a single unrolled pass
-      suffices because the restriction can't oversubscribe again.
+      suffices because the restriction can't oversubscribe again.  An
+      array of width ``M <= n_chips // min_chips`` cannot hold that many
+      active jobs, so there the pass (a sort, its prefix test and the
+      renormalization) is left out of the program: a Python branch on the
+      static width, with the same chips for every input.  A pool of W
+      slots (``sweeps._pool_slots``) takes it exactly when W is at most
+      the chip count over the floor, as 256 slots on 256 chips with a
+      floor of 1 are.
     - **Min-chips overflow trim**: the oracle greedily decrements the job
       maximizing ``base - raw``.  Candidate ``j``'s successive priorities
       are ``-(frac_j + k)``, which fall in disjoint unit bands per trim
@@ -1424,9 +1431,10 @@ def quantize_allocation_jax(
     The trim and the leftover passes are *mutually exclusive* (a trim ends
     with ``sum(base) == n_chips`` exactly, so the remainder is 0; no trim
     means ``K == 0`` and nothing was removed), so one argsort on a
-    conditionally-selected key serves both — two sorts per call, not the
-    three the first port paid.  Tie-breaking is unchanged: each branch
-    sorts the exact key (and stable order) it sorted before.  Every
+    conditionally-selected key serves both — two sorts per call (one where
+    the width rules oversubscription out), not the three the first port
+    paid.  Tie-breaking is unchanged: each branch sorts the exact key (and
+    stable order) it sorted before.  Every
     position test ("among the first k of the sorted order") is
     :func:`~repro.core.ranking.in_stable_prefix`, so the quantizer holds no
     inverse permutation and pays no scatter.
@@ -1449,16 +1457,19 @@ def quantize_allocation_jax(
             return _quantize_capped(theta, n_chips, min_chips, lo, hi, tie)
     cap = n_chips // min_chips  # most jobs the floor allows us to serve
 
-    active0 = theta > 0
-    n_active = jnp.sum(active0, dtype=jnp.int32)
-    # Oversubscribed: serve the largest-theta jobs (stable on ties), queue
-    # the rest with 0, renormalize — the oracle's single recursion, unrolled.
-    key0 = jnp.where(active0, -theta, jnp.inf)
-    servable = active0 & in_stable_prefix(key0, stable_order(key0, tie), cap, tie)
-    over = n_active * min_chips > n_chips
-    sub = jnp.where(servable, theta, 0.0)
-    tot = jnp.sum(sub)
-    theta_eff = jnp.where(over, jnp.where(tot > 0, sub / tot, 0.0), theta)
+    theta_eff = theta
+    if M > cap:
+        # Oversubscribed: serve the largest-theta jobs (stable on ties), queue
+        # the rest with 0, renormalize — the oracle's single recursion, unrolled.
+        active0 = theta > 0
+        n_active = jnp.sum(active0, dtype=jnp.int32)
+        key0 = jnp.where(active0, -theta, jnp.inf)
+        servable = active0 & in_stable_prefix(key0, stable_order(key0, tie), cap,
+                                              tie)
+        over = n_active * min_chips > n_chips
+        sub = jnp.where(servable, theta, 0.0)
+        tot = jnp.sum(sub)
+        theta_eff = jnp.where(over, jnp.where(tot > 0, sub / tot, 0.0), theta)
     active = theta_eff > 0
 
     raw = theta_eff * n_chips

@@ -213,12 +213,16 @@ def test_sort_counts_measured_from_hlo():
 
 def test_engine_scan_pays_one_fewer_sort_per_event_fused():
     """Trip-count-aware histogram of the compiled scan: 4 sorts/event
-    unfused vs 3 fused (+1 one-time arrival-order sort outside the loop)."""
+    unfused vs 3 fused (+1 one-time arrival-order sort outside the loop)
+    where the 16 jobs outnumber the 8 chips.  On 16 chips the unfused
+    rounding's width rules oversubscription out, it leaves that sort out of
+    the program, and it pays 3 as well."""
     m = 16
     x0, arr = _stream(m, seed=2)
-    rule = engine.quantized_rule(hesrpt, 16, dtype=jnp.float64)
 
-    def scan_sorts(fused):
+    def scan_sorts(n_chips, fused):
+        rule = engine.quantized_rule(hesrpt, n_chips, dtype=jnp.float64)
+
         def f(x0v, arrv):
             return engine.run(
                 x0v, arrv, 0.5, rule, pre_arrived=True, fused=fused
@@ -226,8 +230,9 @@ def test_engine_scan_pays_one_fewer_sort_per_event_fused():
 
         return _sorts(f, x0, arr)
 
-    assert scan_sorts(False) == 1 + 4 * m
-    assert scan_sorts(True) == 1 + 3 * m
+    assert scan_sorts(8, False) == 1 + 4 * m
+    assert scan_sorts(8, True) == 1 + 3 * m
+    assert scan_sorts(16, False) == 1 + 3 * m
 
 
 # ------------------------------------- quantizer invariants, fused kernel

@@ -20,14 +20,20 @@ takes.  The matrix spans every single-class policy the whole-chip rule
 takes, Poisson and bursty arrivals, the min-chips floor (whose queue of
 jobs at 0 chips must fit in the pool too) and size-estimation noise (whose
 factors ride in the slots).
+
+The heavy cells' executors at their widths pin how many sorts an event trip
+pays on each path: 3 on the pool of 256 slots for 256 chips, whose width
+leaves the rounding's oversubscription pass out, 4 on the 1000-wide tape.
 """
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import make_policy, make_scenario, sweeps
 from repro.core.arrivals import OnlineSimResult, PoolSimResult, _scenario
+from repro.core.hlo import op_histogram
 
 N_JOBS = 200
 N_CHIPS = 32  # the pool is W = 32 slots, as _pool_slots picks for 32 chips
@@ -66,3 +72,24 @@ def test_pooled_tape_equals_the_full_tape(policy, scenario, min_chips, sigma_siz
     for m in READOUTS:
         np.testing.assert_allclose(float(getattr(pooled, m)), float(getattr(full, m)),
                                    rtol=1e-13, err_msg=m)
+
+
+@pytest.mark.parametrize("pooled,sorts_per_trip", [(True, 3), (False, 4)])
+def test_sorts_per_trip_of_the_heavy_executors(pooled, sorts_per_trip):
+    """The heavy cell's program (256 chips, 1000-job tapes, two lanes): the
+    pool's W = 256 slots are no more than the 256 jobs the floor of one
+    chip serves, so its rounding pays no oversubscription sort; the full
+    tape's 1000 entries are more, and it keeps that sort."""
+    n_jobs = 1000
+    spec = sweeps.Sweep.create(("hesrpt",), (76.8,), n_jobs=n_jobs, n_seeds=2, p=0.5,
+                               n_servers=256.0, n_chips=256)
+    slots = sweeps._pool_slots(spec) if pooled else None
+    assert slots == (256 if pooled else None)
+    keys = jax.random.split(jax.random.PRNGKey(0), spec.n_seeds)
+    with jax.enable_x64(False):
+        f = sweeps._build_fn(spec, "hesrpt", None, False, slots=slots)
+        text = jax.jit(f).lower(keys, jnp.asarray(spec.rates, jnp.float32)
+                                ).compile().as_text()
+    trips = 2 * n_jobs  # one arrival and one departure a job
+    sorts = op_histogram(text)["sort"]
+    assert sorts_per_trip * trips <= sorts < sorts_per_trip * trips + 2

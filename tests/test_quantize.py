@@ -12,6 +12,7 @@ even that branch admits no rounding slack and ties are exercised on
 purpose (equal weights), pinning the stable tie-break order.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -21,6 +22,7 @@ from repro.core.engine import (
     quantize_allocation_jax,
     snap_to_slices_jax,
 )
+from repro.core.hlo import op_histogram
 from repro.sched.quantize import quantize_allocation, snap_to_slices
 
 hypothesis = pytest.importorskip(
@@ -195,6 +197,97 @@ def test_snap_postconditions(cv):
     down = np.array([max([s for s in DEFAULT_SLICES if s <= c], default=0)
                      for c in chips])
     assert np.all(snapped >= down)
+
+
+# ------------------------------------------- width at or below the served cap
+# An array of width M <= n_chips // min_chips cannot hold more active jobs
+# than the floor serves, so the quantizer leaves the oversubscription pass
+# out of its program.  Seeded, not Hypothesis-drawn: Pareto shares, no
+# subnormals, some of them tied on purpose.
+WIDTH_CASES = [
+    # (M, n_chips, min_chips): the heavy pool (M = cap), the floor of 2, the
+    # boundary on either side, and a narrow array far below the cap.
+    (256, 256, 1), (257, 256, 1), (128, 256, 2), (129, 256, 2), (40, 256, 1),
+    (3, 7, 2), (4, 7, 2),
+]
+
+
+def _draws(M, seed, n=24):
+    """``n`` thetas over ``M`` entries (1..M active, every one in the
+    second; Pareto(1.2) shares, a third of the draws with tied shares) and
+    distinct job ids as ties."""
+    rng = np.random.default_rng(seed)
+    thetas = np.zeros((n, M))
+    for i in range(n):
+        k = M if i == 1 else int(rng.integers(1, M + 1))
+        w = rng.pareto(1.2, k) + 0.01
+        if i % 3 == 0:
+            w = np.round(w, 1) + 0.1
+        thetas[i, rng.choice(M, k, replace=False)] = w / w.sum()
+    ties = np.stack([rng.choice(10 * M, M, replace=False) for _ in range(n)])
+    return thetas, ties
+
+
+def _quantize_many(thetas, ties, n_chips, min_chips):
+    """The vmapped quantizer over rows of ``thetas`` (``ties`` None: no tie)."""
+    def one(th, t):
+        return quantize_allocation_jax(th, n_chips, min_chips=min_chips, tie=t)
+
+    ties = None if ties is None else jnp.asarray(ties)
+    return np.asarray(jax.jit(jax.vmap(one))(jnp.asarray(thetas), ties))
+
+
+def _oracle_in_tie_order(theta, tie, n_chips, min_chips):
+    """The NumPy oracle, whose ties break by index, on the entries in the
+    order of ``tie`` (job id), mapped back to their own positions."""
+    order = np.arange(theta.shape[0]) if tie is None else np.argsort(tie, kind="stable")
+    chips = np.zeros(theta.shape[0], np.int64)
+    chips[order] = quantize_allocation(theta[order], n_chips, min_chips=min_chips)
+    return chips
+
+
+@pytest.mark.parametrize("with_tie", [False, True])
+@pytest.mark.parametrize("M,n_chips,min_chips", WIDTH_CASES)
+def test_width_at_most_cap_equals_the_oversubscription_path(M, n_chips, min_chips,
+                                                            with_tie):
+    """The chips of M entries equal the first M of the same thetas padded
+    with inactive entries past the cap (where the oversubscription pass is
+    in the program), and the NumPy oracle's."""
+    thetas, ties = _draws(M, seed=1000 * M + 10 * n_chips + min_chips)
+    ties = ties if with_tie else None
+    got = _quantize_many(thetas, ties, n_chips, min_chips)
+
+    wide = 2 * (n_chips // min_chips) + 1
+    padded = np.zeros((thetas.shape[0], wide))
+    padded[:, :M] = thetas
+    pad_ties = None
+    if ties is not None:
+        pad_ties = np.concatenate(
+            [ties, 10 * M + np.arange(wide - M)[None].repeat(len(ties), 0)], axis=1)
+    ref = _quantize_many(padded, pad_ties, n_chips, min_chips)
+    np.testing.assert_array_equal(got, ref[:, :M])
+    assert np.all(ref[:, M:] == 0)
+
+    for i, theta in enumerate(thetas):
+        tie = None if ties is None else ties[i]
+        np.testing.assert_array_equal(
+            got[i].astype(np.int64),
+            _oracle_in_tie_order(theta, tie, n_chips, min_chips), err_msg=str(i))
+
+
+@pytest.mark.parametrize("with_tie", [False, True])
+@pytest.mark.parametrize("M,n_chips,min_chips", WIDTH_CASES)
+def test_oversubscription_sort_only_above_the_cap(M, n_chips, min_chips, with_tie):
+    """The vmapped quantizer compiles to one sort (the trim and leftover
+    pass) at widths up to ``n_chips // min_chips`` and to two above it."""
+    def one(th, t):
+        return quantize_allocation_jax(th, n_chips, min_chips=min_chips,
+                                       tie=t if with_tie else None)
+
+    text = jax.jit(jax.vmap(one)).lower(
+        jnp.zeros((4, M)), jnp.zeros((4, M), jnp.int32)).compile().as_text()
+    want = 1 if M <= n_chips // min_chips else 2
+    assert op_histogram(text).get("sort", 0) == want
 
 
 @pytest.mark.parametrize("n_chips,min_chips", [(0, 1), (4, 5)])
